@@ -3,8 +3,7 @@
 Every ``row()`` both prints the CSV line and records it in a
 module-level collector, so ``run.py --json`` can snapshot a suite's
 rows into a ``BENCH_<suite>.json`` artifact (see ``repro.obs.export``)
-without re-parsing stdout.  Subprocess-based suites feed their child's
-stdout back through :func:`emit_line` to land in the same collector.
+without re-parsing stdout.
 """
 from __future__ import annotations
 
@@ -67,16 +66,3 @@ def row(name: str, us: float, derived: str = "") -> str:
     print(line, flush=True)
     return line
 
-
-def emit_line(line: str) -> str:
-    """Re-emit one ``name,us,derived`` CSV line from a child process
-    through :func:`row` (collector + stdout).  Non-row lines (warnings
-    a child printed to stdout) pass through unrecorded."""
-    parts = line.split(",", 2)
-    if len(parts) == 3:
-        try:
-            return row(parts[0], float(parts[1]), parts[2])
-        except ValueError:
-            pass
-    print(line, flush=True)
-    return line

@@ -1,16 +1,16 @@
 """Sharded edge-fleet streaming: fleet items/sec and step latency vs E.
 
 Drives ``FleetExecutor`` — E edge shards as one ``shard_map`` step with
-core escalation over a single all-to-all — for E in {1, 4, 8} under 8
-forced host devices, and reports sustained fleet throughput, median and
+core escalation over a single all-to-all — for E in {1, 4, 8}, as far
+as the devices reach, and reports sustained fleet throughput, median and
 p99 per-step latency, and the jit trace count (asserted == 1: the whole
 fleet tick is one XLA executable).  Emits the same CSV row schema as
 ``benchmarks/streaming.py``, including the event-time lineage rows
 (per-stage ``fleet/E*_lat_*`` percentiles), the warmup-excluded device
 step histogram, and the ``fleet/E*_cost`` roofline coordinates from
 ``obs.costmodel``; a ``fused=1`` lane re-runs the widest shape with
-the per-shard fused-tick kernel (``fleet/E8_fused_*`` rows, counters
-asserted equal to the staged lane's).
+the per-shard fused-tick kernel (``fleet/E<widest>_fused_*`` rows,
+counters asserted equal to the staged lane's).
 
 ``--faults`` runs the degraded-fleet smoke instead: a
 ``FleetController`` drives the elastic core budget and the
@@ -28,20 +28,23 @@ an exact host-side recomputation, and ``trace_count <= 1 + retraces +
 remeshes`` (the leave/join itself stays on ONE trace — membership is
 an operand).
 
-``--regions`` runs the hierarchical-federation smoke: the same 8
-devices arranged as ``(R, E)`` region meshes for R in {1, 2, 4} under
-a fixed per-region fog budget, measuring step latency per shape and
+``--regions`` runs the hierarchical-federation smoke: the same
+devices arranged as ``(R, E)`` region meshes for R in {1, 2, 4}, at
+E >= 2 shards per region, under a fixed per-region fog budget, measuring step latency per shape and
 accounting the two-hop exchange volume.  Asserted: cross-region bytes
 derive from the fog *budget* and are independent of the region width E
 (the flat single-hop exchange grows with E), and every shape runs its
 whole measured window on ONE trace.
 
-The measurement runs in a subprocess: the forced host device count must
-be set before jax first initializes, and the parent harness has long
-since locked in its own platform.
+Every suite runs in the calling process on the devices JAX finds: the
+fleet is as wide as ``min(8, jax.device_count())``.  The default suite
+runs the shard counts of ``SHARD_COUNTS`` that fit; ``--faults``,
+``--churn`` and ``--regions`` need at least 4 devices and are skipped,
+with a message, on fewer.  On a CPU host, ask for 8 virtual devices in
+the environment before JAX starts:
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
 import os
-import subprocess
 import sys
 
 D = 16            # sensor feature width
@@ -49,28 +52,32 @@ BATCH = 256       # items per shard per micro-batch
 STEPS = 100
 WARMUP = 5
 SHARD_COUNTS = (1, 4, 8)
+MAX_SHARDS = SHARD_COUNTS[-1]
+MIN_SHARDS = 4    # the faults/churn/regions suites' narrowest fleet
 
 
 def bench(faults: bool = False, churn: bool = False,
           regions: bool = False):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["JAX_PLATFORMS"] = "cpu"
-    args = ["--child"] + (["--faults"] if faults else []) \
-        + (["--churn"] if churn else []) \
-        + (["--regions"] if regions else [])
-    out = subprocess.run([sys.executable, "-m", "benchmarks.fleet"] + args,
-                         env=env, capture_output=True,
-                         text=True, timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError("fleet bench subprocess failed:\n"
-                           + out.stderr[-2000:])
-    from benchmarks.common import emit_line
-    for line in out.stdout.strip().splitlines():
-        emit_line(line)                # re-record for run.py --json
+    import jax
+    width = min(MAX_SHARDS, jax.device_count())
+    if faults or churn or regions:
+        if width < MIN_SHARDS:
+            print(f"# fleet: skipping the suite: it needs {MIN_SHARDS} "
+                  f"devices, JAX found {jax.device_count()} (on a CPU host "
+                  f"set XLA_FLAGS=--xla_force_host_platform_device_count=8 "
+                  f"before starting)", file=sys.stderr)
+            return
+    if churn:
+        _churn(width)
+    elif faults:
+        _faults(width)
+    elif regions:
+        _regions(width)
+    else:
+        _widths(width)
 
 
-def _child():
+def _widths(width: int):
     import time
 
     import jax
@@ -98,7 +105,8 @@ def _child():
         jnp.float32)
     scfg = StreamConfig(micro_batch=BATCH, window=64, stride=32,
                         capacity=4 * BATCH, lateness=64.0)
-    for e in SHARD_COUNTS:
+    counts = [e for e in SHARD_COUNTS if e <= width]
+    for e in counts:
         engine = rules.RuleEngine([
             rules.threshold_rule("hot_mean", 0, ">=", 0.25,
                                  rules.C_SEND_CORE, priority=1),
@@ -138,7 +146,7 @@ def _child():
             f"/{m['fleet']['windows_emitted']}"
             f";overflow={m['fleet_core_overflow']}"
             f";traces={ex.trace_count}")
-        if e == SHARD_COUNTS[-1]:
+        if e == counts[-1]:
             staged_fleet_counters = (m["fleet"]["windows_escalated"],
                                      m["fleet"]["windows_emitted"])
         # the in-step device histogram's view of the same run (warmup/
@@ -181,7 +189,7 @@ def _child():
     # lane's (parity is pinned record-level in tests; the fleet-level
     # escalation totals are re-asserted here so the bench itself would
     # catch a divergence), so only throughput/latency re-report.
-    e = SHARD_COUNTS[-1]
+    e = counts[-1]
     engine = rules.RuleEngine([
         rules.threshold_rule("hot_mean", 0, ">=", 0.25,
                              rules.C_SEND_CORE, priority=1),
@@ -228,7 +236,7 @@ def _child():
 
 
 def _hot_fixture():
-    """The degraded/churned children's shared workload: tanh core
+    """The degraded and churned suites' shared workload: tanh core
     stage, hot-mean escalation rule, tumbling 64/64 stream config
     (tumbling: a stall gap or a foreign-slot replay cannot smear
     window boundaries).  One copy, so --faults and --churn measure the
@@ -265,7 +273,7 @@ def _hot_fixture():
     return engine, scfg, make_pipeline
 
 
-def _child_faults():
+def _faults(E: int):
     """Degraded-fleet smoke: stall one shard mid-run under an elastic
     budget and report what the control plane did about it."""
     import time
@@ -282,7 +290,7 @@ def _child_faults():
                                     FleetConfig, FleetController,
                                     FleetExecutor)
 
-    E, steps = 8, 60
+    steps = 60
     stall = Fault(shard=2, start=20, end=32)
     sched = FaultSchedule([stall])
     engine, scfg, make_pipeline = _hot_fixture()
@@ -376,7 +384,7 @@ def _child_faults():
     log.close()
 
 
-def _child_churn():
+def _churn(E: int):
     """Membership-churn smoke: a shard leaves mid-run, its stream
     replays on the reassignment-chosen backup, a joiner restores the
     slot, and the fleet then truly re-meshes — all verified against a
@@ -394,7 +402,7 @@ def _child_churn():
                                     FleetConfig, FleetController,
                                     FleetExecutor)
 
-    E, steps = 8, 60
+    steps = 60
     event = Churn(shard=3, leave=20, join=34)
     sched = FaultSchedule(churn=[event])
     engine, scfg, make_pipeline = _hot_fixture()
@@ -499,8 +507,8 @@ def _child_churn():
     assert sum(m["shard"]["items_late"]) == 0, "churn dropped records"
     assert ex.trace_count == 1, f"membership retraced: {ex.trace_count}"
 
-    # true re-mesh: the departed device never comes back — shrink to 7
-    devs = [d for j, d in enumerate(jax.devices()) if j != event.shard]
+    # true re-mesh: the departed device never comes back — shrink to E - 1
+    devs = [d for j, d in enumerate(jax.devices()[:E]) if j != event.shard]
     keep = [j for j in range(E) if j != event.shard]
     state, payload = ctl.remesh(state, devs, keep=keep)
     base, ts = feed(steps)
@@ -538,7 +546,7 @@ def _child_churn():
     log.close()
 
 
-def _child_regions():
+def _regions(S: int):
     """Hierarchical-federation smoke: the same device budget arranged
     as (R, E) region meshes, with the two-hop exchange volume accounted
     against the flat single-hop baseline."""
@@ -552,7 +560,7 @@ def _child_regions():
     from repro.obs import Tracer
     from repro.stream.fleet import FleetConfig, FleetExecutor
 
-    S, steps = 8, 40
+    steps = 40
     FOG = 8                             # fixed per-region fog budget
     engine, scfg, make_pipeline = _hot_fixture()
     rw = 5 + D                          # escalation record row width
@@ -562,7 +570,7 @@ def _child_regions():
     # untouched while the flat single-hop exchange keeps growing
     def geom(r, eper):
         return FleetConfig(stream=scfg, num_shards=r * eper,
-                           num_core=2, core_budget=2 * S,
+                           num_core=2, core_budget=2 * MAX_SHARDS,
                            num_regions=r, fog_budget=FOG).exchange()
 
     widths = (2, 4, 8, 16)
@@ -572,11 +580,13 @@ def _child_regions():
     assert all(b > a for a, b in zip(flat, flat[1:])), flat
     # ... and scales with the budget it is derived from
     big = FleetConfig(stream=scfg, num_shards=8, num_core=2,
-                      core_budget=2 * S, num_regions=2,
+                      core_budget=2 * MAX_SHARDS, num_regions=2,
                       fog_budget=4 * FOG).exchange()
     assert big.cross_region_bytes(rw) > cross[0]
 
-    for r in (1, 2, 4):
+    # a region of one shard has no intra-region hop to save: the two-hop
+    # exchange only pays off from two shards per region up
+    for r in (r for r in (1, 2, 4) if S // r >= 2):
         eper = S // r
         cfg = FleetConfig(stream=scfg, num_shards=S,
                           num_core=min(2, eper), core_budget=2 * S,
@@ -633,15 +643,5 @@ def _child_regions():
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        if "--churn" in sys.argv:
-            _child_churn()
-        elif "--faults" in sys.argv:
-            _child_faults()
-        elif "--regions" in sys.argv:
-            _child_regions()
-        else:
-            _child()
-    else:
-        bench(faults="--faults" in sys.argv, churn="--churn" in sys.argv,
-              regions="--regions" in sys.argv)
+    bench(faults="--faults" in sys.argv, churn="--churn" in sys.argv,
+          regions="--regions" in sys.argv)

@@ -21,6 +21,7 @@ import sys
 from benchmarks import (common, fleet, ingest, messaging, pipeline_e2e,
                         roofline_report, routing, scaling, store_query,
                         streaming, tiering)
+from repro.compile_cache import use_compile_cache
 
 SUITES = {
     "tiering": tiering.bench,          # paper Table I
@@ -61,6 +62,7 @@ def main(argv: list | None = None) -> None:
               file=sys.stderr)
         raise SystemExit(2)
     which = names or list(SUITES)
+    use_compile_cache()
     failed = []
     print("name,us_per_call,derived")
     for name in which:

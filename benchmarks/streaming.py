@@ -6,7 +6,13 @@ over a sustained sensor stream with rule-gated escalation.  Drives the
 engine -> capacity-bounded core escalation — and reports sustained
 throughput, median and p99 per-step latency, and the jit trace count
 (must be exactly 1 after warmup: the whole loop is one XLA executable).
+
+The Pallas lanes compile the real TPU kernels.  Elsewhere they run only
+in Pallas interpret mode, and only when the caller asks for it with
+``REPRO_PALLAS_INTERPRET=1``; without it a host with no TPU skips them.
 """
+import os
+import sys
 import time
 
 import jax
@@ -23,6 +29,7 @@ D = 16            # sensor feature width
 BATCH = 256       # items per micro-batch
 STEPS = 200
 WARMUP = 5
+INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET") == "1"
 
 
 def _edge_fn(p, batch):
@@ -40,12 +47,10 @@ def _core_fn(p, batch):
 
 def _executor(backend: str, fused: bool = False,
               overlap: bool = False) -> tuple[StreamExecutor, object]:
-    # interpret everywhere the TPU kernel can't compile; only on TPU do
-    # the pallas rows measure the real kernel
-    interpret = backend == "pallas" and jax.default_backend() != "tpu"
     cfg = StreamConfig(micro_batch=BATCH, window=64, stride=32,
                        capacity=4 * BATCH, lateness=64.0, backend=backend,
-                       interpret=interpret, fused=fused,
+                       interpret=backend == "pallas" and INTERPRET,
+                       fused=fused,
                        overlap_ingest=overlap)
     engine = rules.RuleEngine([
         rules.threshold_rule("hot_mean", 0, ">=", 0.25, rules.C_SEND_CORE,
@@ -80,7 +85,14 @@ def _drive(ex, state, steps):
 
 
 def bench():
-    for backend in ("jnp", "pallas"):
+    backends = ["jnp"]
+    if INTERPRET or jax.default_backend() == "tpu":
+        backends.append("pallas")
+    else:
+        print(f"# streaming: skipping the pallas lanes: no TPU "
+              f"({jax.default_backend()}) and REPRO_PALLAS_INTERPRET "
+              f"is not 1", file=sys.stderr)
+    for backend in backends:
       for fused in (False, True):
         ex, state = _executor(backend, fused=fused)
         state, _ = _drive(ex, state, WARMUP)

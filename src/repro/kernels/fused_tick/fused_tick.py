@@ -124,7 +124,10 @@ def fused_reduce_2d(x2d: jnp.ndarray, valid: jnp.ndarray, window: int,
     vtile = jnp.broadcast_to(
         valid.astype(jnp.float32)[:, None], (r, LANES))
     grid = (n_out // block_rows, l // LANES)
-    out = jax.ShapeDtypeStruct((n_out, l), jnp.float32)
+    # inside a fleet shard_map the outputs vary over the mesh axes the
+    # rows do (jax.shard_map checks that on every out_shape)
+    out = jax.ShapeDtypeStruct((n_out, l), jnp.float32,
+                               vma=jax.typeof(x2d).vma)
     return pl.pallas_call(
         functools.partial(_kernel, window=window, block_rows=block_rows,
                           table=tuple(table), min_count=min_count),

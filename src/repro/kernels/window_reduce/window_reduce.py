@@ -15,7 +15,8 @@ filling invalid rows with the reduction identity, so the kernel stays a
 pure dense reduction.
 
 VMEM: the whole [R, 128] row range of one lane tile must fit on chip
-(R * 512 bytes), fine for micro-batch blocks (R <= ~16k rows).
+(R * 512 bytes).  For v5e the compiler accepts 65,536 and 98,304 rows
+and refuses 131,072 (out of VMEM).
 """
 from __future__ import annotations
 
@@ -66,6 +67,7 @@ def sliding_reduce_2d(x2d: jnp.ndarray, window: int, *, op: str = "sum",
         grid=grid,
         in_specs=[pl.BlockSpec((r, LANES), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_out, l), x2d.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_out, l), x2d.dtype,
+                                       vma=jax.typeof(x2d).vma),
         interpret=interpret,
     )(x2d)

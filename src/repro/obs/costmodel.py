@@ -74,10 +74,13 @@ def analyze(jitted, *args, **kwargs) -> dict:
     Compiling here hits jax's compilation cache when the executor has
     already traced the same shapes, so the pass is cheap to run after
     warmup."""
-    compiled = jitted.lower(*args, **kwargs).compile()
+    return cost_of(jitted.lower(*args, **kwargs).compile())
+
+
+def cost_of(compiled) -> dict:
+    """:func:`analyze` of an already compiled executable
+    (``jax.stages.Compiled``)."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):        # older jax: list of dicts
-        ca = ca[0] if ca else {}
     totals = {
         "flops": float(ca.get("flops", 0.0)),
         "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -33,15 +33,8 @@ import threading
 import time
 
 import numpy as np
-
-try:                                       # profiler hooks are optional:
-    from jax.profiler import (             # a headless CPU build without
-        StepTraceAnnotation,               # profiling support still traces
-        TraceAnnotation,
-        trace as _jax_trace,
-    )
-except Exception:                          # pragma: no cover
-    StepTraceAnnotation = TraceAnnotation = _jax_trace = None
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+from jax.profiler import trace as _jax_trace
 
 _NULL_CTX = contextlib.nullcontext()
 
@@ -76,15 +69,11 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     @contextlib.contextmanager
     def _span(self, name: str, args: dict):
-        ann = TraceAnnotation(name) if TraceAnnotation is not None else None
         t0 = time.perf_counter()
-        if ann is not None:
-            ann.__enter__()
         try:
-            yield self
+            with TraceAnnotation(name):
+                yield self
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
             t1 = time.perf_counter()
             with self._lock:
                 self._spans.append((name, t0, t1,
@@ -101,7 +90,7 @@ class Tracer:
         """``jax.profiler.StepTraceAnnotation`` for one tick: groups
         the tick's device ops under a step marker in the trace viewer
         (the profiler's per-step breakdown needs it)."""
-        if not self.enabled or StepTraceAnnotation is None:
+        if not self.enabled:
             return _NULL_CTX
         return StepTraceAnnotation(name, step_num=step_num)
 
@@ -109,7 +98,7 @@ class Tracer:
         """Capture a full XLA profile (device ops + host annotations)
         to ``logdir`` while the context is open.  View with
         TensorBoard's profile plugin or https://ui.perfetto.dev."""
-        if not self.enabled or _jax_trace is None:
+        if not self.enabled:
             return _NULL_CTX
         return _jax_trace(logdir)
 

@@ -4,7 +4,7 @@ from repro.runtime.compression import (cross_pod_allreduce, compress_tree,  # no
                                        decompress_tree, dequantize,
                                        init_errors, quantize)
 from repro.runtime.elastic import (ElasticBudget, rebuild_overlay,  # noqa: F401
-                                   remesh, reshard_state)
+                                   remesh)
 from repro.runtime.health import HealthMonitor  # noqa: F401
 from repro.runtime.overlap import IngestStager, microbatched_grads  # noqa: F401
 from repro.runtime.straggler import StragglerDetector  # noqa: F401

@@ -1,9 +1,9 @@
-"""Elastic scaling: re-mesh + re-shard a training state.
+"""Elastic scaling: re-mesh over a changed device set.
 
 A job checkpointed on mesh A resumes on mesh B (more pods, fewer pods,
 or a degraded pod with failed chips carved out).  The checkpoint layer
-stores unsharded logical arrays; this module recomputes shardings for
-the new mesh and re-places state.  The quadtree overlay is rebuilt from
+stores unsharded logical arrays; this module builds the new mesh, and
+the owner of the state places it there.  The quadtree overlay is rebuilt from
 the new mesh shape (the paper's join/rebootstrap phase, done at
 re-launch time rather than via runtime discovery messages).
 
@@ -16,7 +16,6 @@ to the core sub-mesh's per-tick work budget instead of its chip count.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
 
 import jax
 import numpy as np
@@ -86,14 +85,6 @@ def remesh(old_shape: dict, new_devices: list, axis_names: tuple,
             shape = (lead, model)
     devs = np.asarray(new_devices[: int(np.prod(shape))]).reshape(shape)
     return jax.sharding.Mesh(devs, axis_names)
-
-
-def reshard_state(state, sharding_fn: Callable, mesh) -> object:
-    """Re-place a host-side state pytree under ``sharding_fn(mesh)``
-    (same rules, new mesh) — the elastic-resume hot path."""
-    shardings = sharding_fn(mesh)
-    return jax.tree.map(
-        lambda a, s: jax.device_put(a, s), state, shardings)
 
 
 @dataclasses.dataclass

@@ -484,17 +484,6 @@ class StreamExecutor:
         """Number of step traces so far — 1 after warmup, forever."""
         return self._traces
 
-    def _compile_count(self) -> int:
-        """Compiled step executables (>= trace_count: one trace can
-        compile again for new input shardings — e.g. the donated
-        histogram buffers come back device-committed after tick 0 —
-        which ``_traces`` never sees but costs compile-scale wall
-        time all the same)."""
-        try:
-            return int(self._jstep._cache_size())
-        except Exception:             # non-pjit stand-ins in tests
-            return self._traces
-
     def set_tracer(self, tracer) -> None:
         """Install an ``obs.Tracer`` for host-span instrumentation of
         ``step()`` (dispatch span + JAX profiler step annotation).
@@ -521,20 +510,30 @@ class StreamExecutor:
         Resolution is one tick (see ``obs.latency``)."""
         return OL.lineage_percentiles(self._lineage, qs)
 
-    def step_cost(self, state: StreamState, items: jnp.ndarray,
-                  ts: jnp.ndarray) -> dict:
-        """XLA cost analysis of ONE tick at these operand shapes
-        (``obs.costmodel.analyze``): total FLOPs/bytes plus a per-
-        ``named_scope``-stage breakdown.  Lower + compile only —
-        nothing executes, no state is consumed — and after warmup the
-        compile hits jax's cache (same shapes as the traced step), so
-        this is safe to call on a live executor."""
-        return OC.analyze(
-            self._jstep, state, jnp.asarray(items), jnp.asarray(ts),
+    def lower(self, state: StreamState, items, ts) -> jax.stages.Lowered:
+        """Lower ONE tick at these operands without running it.
+        ``state``/``items``/``ts`` may be arrays or
+        ``jax.ShapeDtypeStruct``s (an ahead-of-time compile for a chip
+        that is described, not attached); ``.compile()`` gives the
+        executable, its HLO (``as_text()``) and ``memory_analysis()``.
+        Nothing executes and no state is consumed."""
+        return self._jstep.lower(
+            state, items, ts,
             jnp.asarray(self._effective_budget(), jnp.int32),
             self._lat_hist, self._lineage,
             jnp.asarray(0.0, jnp.float32), jnp.asarray(0.0, jnp.float32),
             jnp.asarray(I.MODE_LIVE, jnp.int32))
+
+    def step_cost(self, state: StreamState, items: jnp.ndarray,
+                  ts: jnp.ndarray) -> dict:
+        """XLA cost analysis of ONE tick at these operand shapes
+        (``obs.costmodel.cost_of``): total FLOPs/bytes plus a per-
+        ``named_scope``-stage breakdown.  Lower + compile only —
+        nothing executes, no state is consumed — and after warmup the
+        compile hits jax's cache (same shapes as the traced step), so
+        this is safe to call on a live executor."""
+        return OC.cost_of(self.lower(state, jnp.asarray(items),
+                                     jnp.asarray(ts)).compile())
 
     @property
     def core_budget(self) -> int | None:
@@ -637,7 +636,7 @@ class StreamExecutor:
         feed = 0.0 if self._skip_feed else self.last_step_seconds
         if self._skip_feed and self.last_step_seconds > 0.0:
             self.warmup_excluded += 1
-        compiles_before = self._compile_count()
+        traces_before = self._traces
         t0 = time.perf_counter()
         with self.tracer.step_annotation("stream_step", self._step_num), \
                 self.tracer.span("stream.dispatch", step=self._step_num):
@@ -649,7 +648,7 @@ class StreamExecutor:
                 jnp.asarray(time.perf_counter() - self._t0, jnp.float32),
                 jnp.asarray(mode, jnp.int32))
         self.last_step_seconds = time.perf_counter() - t0
-        self._skip_feed = self._compile_count() > compiles_before
+        self._skip_feed = self._traces > traces_before
         return state, out
 
     def run(self, state: StreamState,

@@ -49,10 +49,10 @@ Fleet **churn** (devices leave and join) is handled at two granularities:
   :meth:`FleetExecutor.remesh` rebuilds the mesh over the survivors
   (``runtime.elastic.remesh`` on the ``("region", "edge")`` axes,
   resizing one axis per call),
-  re-shards the state with ``runtime.elastic.reshard_state``
-  (surviving rows migrate; a departed shard's unconsumed ring rows
-  come back to the host as the backup-replay payload and its counters
-  fold into a surviving row), and costs exactly one re-trace
+  places the migrated state on the new mesh (surviving rows migrate; a
+  departed shard's unconsumed ring rows come back to the host as the
+  backup-replay payload and its counters fold into a surviving row),
+  and costs exactly one re-trace
   (``trace_count <= 1 + retraces + remeshes``).
 
 Backup replay rides the ``mode`` per-shard operand (``stream.ingest``'s
@@ -75,7 +75,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.runtime import elastic
@@ -343,6 +342,7 @@ class FleetExecutor:
         # previous tick's wall time — zero recompiles, updated inside
         # the same jit as the fleet step, outside the shard_map)
         self.tracer = NULL_TRACER
+        # (both banks move onto the mesh in init_state, with the state)
         self._lat_hist = OL.histogram_init()
         # event-time latency lineage: one [n_stages, buckets] histogram
         # bank PER SHARD ([S, n_stages, buckets], sharded like the
@@ -353,13 +353,9 @@ class FleetExecutor:
         self._lineage = jnp.tile(OL.lineage_init()[None],
                                  (cfg.num_shards, 1, 1))
         self._t0 = time.perf_counter()     # lineage epoch (f32 stamps)
-        # warmup exclusion: a tick that compiled measures
-        # compile+execute wall time — withhold it from the NEXT tick's
-        # histogram feed (see step()).  Keyed on the jit *executable*
-        # cache, not the trace counter: tick 1 re-compiles the same
-        # trace for device-committed input shardings (the donated
-        # histogram buffers come back sharded), which _traces never
-        # sees but costs compile-scale wall time all the same.
+        # warmup exclusion: a tick that traced measures compile+execute
+        # wall time — withhold it from the NEXT tick's histogram feed
+        # (see step())
         self._skip_feed = False
         self.warmup_excluded = 0
         self._step_num = 0
@@ -373,6 +369,18 @@ class FleetExecutor:
         self.measure_steps = True
         self._build()
 
+    def _shard_spec(self) -> P:
+        """Spec of every [S]-leading leaf: the shard axis splits over
+        both mesh axes, region-major."""
+        return P((self.cfg.region_axis, self.cfg.axis_name))
+
+    def _place(self, tree, spec: P):
+        """Put a pytree on the current mesh with the sharding the step
+        hands it back with.  Tick 0 then sees the same input shardings
+        as every later tick: jit keys its trace on each argument's mesh,
+        so an unplaced first operand would cost a second trace."""
+        return jax.device_put(tree, NamedSharding(self.mesh, spec))
+
     def _build(self) -> None:
         """(Re)build the jitted fleet step for the current static slot
         ceilings, mesh, and shard count.  Called once at init and again
@@ -383,12 +391,13 @@ class FleetExecutor:
         cfg = self.cfg
         # [S]-leading leaves shard over both mesh axes (region-major);
         # the per-region fog budgets [R] shard over the region axis only
-        spec = P((cfg.region_axis, cfg.axis_name))
+        spec = self._shard_spec()
         rspec = P(cfg.region_axis)
-        sharded = shard_map(self._fleet_step, mesh=self.mesh,
-                            in_specs=(spec, spec, spec, spec, spec, spec,
-                                      spec, P(), rspec, spec, P()),
-                            out_specs=(spec, spec, spec))
+        sharded = jax.shard_map(self._fleet_step, mesh=self.mesh,
+                                in_specs=(spec, spec, spec, spec, spec,
+                                          spec, spec, P(), rspec, spec,
+                                          P()),
+                                out_specs=(spec, spec, spec))
 
         def _traced(state, items, ts, offered, mode, healthy, active,
                     budget, region_budget, lat_hist, lineage, last_dt,
@@ -405,7 +414,14 @@ class FleetExecutor:
                 lat_hist = OL.histogram_update(lat_hist, last_dt)
             return (new_state, out), lat_hist, lineage
 
-        self._jstep = jax.jit(_traced, donate_argnums=(0, 9, 10))
+        # outputs pinned to the shardings _place gives the carried
+        # operands: left free, XLA may hand back an equivalent spec
+        # (size-1 mesh axes dropped), which jit keys as a new executable
+        sharded_out = NamedSharding(self.mesh, spec)
+        self._jstep = jax.jit(
+            _traced, donate_argnums=(0, 9, 10),
+            out_shardings=(sharded_out, NamedSharding(self.mesh, P()),
+                           sharded_out))
 
     # -- control-plane knobs (host-side, between ticks) --------------------
     @property
@@ -562,25 +578,45 @@ class FleetExecutor:
         return np.asarray(jax.device_get(self._lineage),
                           np.int64).sum(axis=0)
 
-    def step_cost(self, state: FleetState, items: jnp.ndarray,
-                  ts: jnp.ndarray) -> dict:
-        """XLA cost analysis of ONE fleet tick at these operand shapes
-        (``obs.costmodel.analyze``): whole-executable FLOPs/bytes plus
-        the per-``named_scope``-stage breakdown (exchange hops, core
-        compute, commit...).  Lower + compile only — nothing executes —
-        and after warmup the compile hits jax's cache."""
-        offered = jnp.ones(jnp.asarray(ts).shape, bool)
-        return OC.analyze(
-            self._jstep, state, jnp.asarray(items), jnp.asarray(ts),
-            offered, jnp.zeros(self.cfg.num_shards, jnp.int32),
+    def lower(self, state: FleetState, items, ts) -> jax.stages.Lowered:
+        """Lower ONE fleet tick at these operands without running it
+        (every shard offering its whole batch, live mode).  ``state``/
+        ``items``/``ts`` may be arrays or ``jax.ShapeDtypeStruct``s
+        sharded over a mesh of described devices (an ahead-of-time
+        compile); ``.compile()`` gives the executable, its HLO
+        (``as_text()``) and ``memory_analysis()``."""
+        return self._jstep.lower(
+            state, items, ts, jnp.ones(ts.shape, bool),
+            jnp.zeros(self.cfg.num_shards, jnp.int32),
             jnp.asarray(self._healthy), jnp.asarray(self._active),
             jnp.asarray(self._budget, jnp.int32),
             jnp.asarray(self._region_budget, jnp.int32),
             self._lat_hist, self._lineage,
             jnp.asarray(0.0, jnp.float32), jnp.asarray(0.0, jnp.float32))
 
+    def step_cost(self, state: FleetState, items: jnp.ndarray,
+                  ts: jnp.ndarray) -> dict:
+        """XLA cost analysis of ONE fleet tick at these operand shapes
+        (``obs.costmodel.cost_of``): whole-executable FLOPs/bytes plus
+        the per-``named_scope``-stage breakdown (exchange hops, core
+        compute, commit...).  Lower + compile only — nothing executes —
+        and after warmup the compile hits jax's cache."""
+        return OC.cost_of(self.lower(state, jnp.asarray(items),
+                                     jnp.asarray(ts)).compile())
+
     # -- state ------------------------------------------------------------
     def init_state(self, feature_dim: int) -> FleetState:
+        """Fresh fleet state on the mesh, with the shardings the step
+        returns.  The executor's latency histogram and lineage banks
+        ride the step beside the state, so they move onto the mesh here
+        too (their counts carry over)."""
+        self._lat_hist = self._place(self._lat_hist, P())
+        self._lineage = self._place(self._lineage, self._shard_spec())
+        return self._place(self._fresh_state(feature_dim),
+                           self._shard_spec())
+
+    def _fresh_state(self, feature_dim: int) -> FleetState:
+        """Zeroed fleet state, not yet placed on the mesh."""
         cfg, E = self.cfg.stream, self.cfg.num_shards
 
         def tile(x):
@@ -618,15 +654,6 @@ class FleetExecutor:
     def trace_count(self) -> int:
         """Number of fleet-step traces so far — 1 after warmup."""
         return self._traces
-
-    def _compile_count(self) -> int:
-        """Compiled fleet-step executables (>= trace_count: one trace
-        can compile twice — numpy-committed inputs on tick 0, sharded
-        device-resident donations from tick 1 on)."""
-        try:
-            return int(self._jstep._cache_size())
-        except Exception:             # non-pjit stand-ins in tests
-            return self._traces
 
     # -- the single-trace fleet tick ---------------------------------------
     def _fleet_step(self, state: FleetState, items: jnp.ndarray,
@@ -821,14 +848,14 @@ class FleetExecutor:
                     "rows queued past their lateness-exempt tick")
         self._step_num += 1
         # warmup exclusion: the previous tick's wall time is the
-        # histogram feed — unless that tick compiled, in which case it
+        # histogram feed — unless that tick traced, in which case it
         # measured compile+execute and would pollute the tail (the
         # p99-vs-p95 cliff the BENCH baselines showed).  Feed 0.0
         # instead (histogram_update skips non-positive) and count it
         feed = 0.0 if self._skip_feed else self.last_step_seconds
         if self._skip_feed and self.last_step_seconds > 0.0:
             self.warmup_excluded += 1
-        compiles_before = self._compile_count()
+        traces_before = self._traces
         t0 = time.perf_counter()
         with self.tracer.step_annotation("fleet_tick", self._step_num):
             with self.tracer.span("fleet.dispatch", step=self._step_num):
@@ -848,7 +875,7 @@ class FleetExecutor:
                                       step=self._step_num):
                     jax.block_until_ready(out)
         self.last_step_seconds = time.perf_counter() - t0
-        self._skip_feed = self._compile_count() > compiles_before
+        self._skip_feed = self._traces > traces_before
         return out
 
     # -- true re-mesh (the device set changed) ------------------------------
@@ -867,10 +894,11 @@ class FleetExecutor:
         change; pass ``num_regions`` to resize the region axis instead
         (the edge width must then stay ``len(devices) // num_regions ==
         edges_per_region``; resizing both axes at once is two remesh
-        calls).  The re-laid-out state is placed with
-        ``runtime.elastic.reshard_state``.  Costs exactly one re-trace
-        on the next step (``trace_count <= 1 + retraces + remeshes`` —
-        the re-trace discipline the tests and benchmarks assert).
+        calls).  The re-laid-out state, latency histogram and lineage
+        banks are placed on the new mesh with the step's shardings.
+        Costs exactly one re-trace on the next step (``trace_count <= 1
+        + retraces + remeshes`` — the re-trace discipline the tests and
+        benchmarks assert).
 
         ``keep``: for each NEW slot (region-major flat numbering), the
         OLD shard index whose state row (ring buffer, window carry,
@@ -975,7 +1003,7 @@ class FleetExecutor:
             num_core=min(cfg.num_core, new_ee) if num_core is None
             else num_core)
         self.mesh = new_mesh
-        fresh = jax.device_get(self.init_state(feature_dim))
+        fresh = jax.device_get(self._fresh_state(feature_dim))
         new_host = jax.tree.map(
             lambda o, f: np.stack(
                 [np.asarray(o[k]) if k is not None else np.asarray(f[j])
@@ -1031,25 +1059,17 @@ class FleetExecutor:
         self._active = np.asarray(
             [self._active[k] if k is not None else True for k in keep])
         # the latency histogram survives the remesh, but its buffer is
-        # committed to the OLD device set — rehost it so the next step
-        # can place it on the new mesh
-        self._lat_hist = jnp.asarray(np.asarray(jax.device_get(
-            self._lat_hist)))
+        # committed to the OLD device set — move it to the new mesh
+        self._lat_hist = self._place(jax.device_get(self._lat_hist), P())
         # the lineage banks are per-shard state: fold departed rows into
         # their counter-fold survivor (histogram merge — totals survive
         # the shrink), then renumber by keep (joiners start zeroed)
         lin = np.array(np.asarray(jax.device_get(self._lineage)))
         for src, dst in fold_counters.items():
             lin[dst] = OL.histogram_merge(lin[dst], lin[src])
-        self._lineage = jnp.asarray(np.stack(
+        self._lineage = self._place(np.stack(
             [lin[k] if k is not None else np.zeros_like(lin[0])
-             for k in keep]))
+             for k in keep]), self._shard_spec())
         self._remeshes += 1
         self._build()                          # one re-trace, next step
-        spec = P((self.cfg.region_axis, self.cfg.axis_name))
-        new_state = elastic.reshard_state(
-            new_host,
-            lambda mesh: jax.tree.map(
-                lambda _: NamedSharding(mesh, spec), new_host),
-            new_mesh)
-        return new_state, departed
+        return self._place(new_host, self._shard_spec()), departed

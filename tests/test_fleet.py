@@ -30,6 +30,17 @@ _SCRIPT = textwrap.dedent("""
     from repro.stream import StreamConfig, StreamExecutor
     from repro.stream.fleet import FleetConfig, FleetExecutor
 
+    def assert_placed(fx, state, items, ts):
+        # the carried operands (state, latency histogram, lineage banks)
+        # already hold the shardings the compiled step hands back, so
+        # tick 0 keys the same trace as every later tick
+        (st_sh, _), hist_sh, lin_sh = fx.lower(
+            state, items, ts).compile().output_shardings
+        for leaf, sh in zip(jax.tree.leaves(state), jax.tree.leaves(st_sh)):
+            assert leaf.sharding == sh, (leaf.sharding, sh)
+        assert fx._lat_hist.sharding == hist_sh, fx._lat_hist.sharding
+        assert fx._lineage.sharding == lin_sh, fx._lineage.sharding
+
     D, BATCH = 3, 32
     edge_fn = lambda p, b: (b * 1.5, b[:, :5])
     core_fn = lambda p, b: (b + 100.0, b[:, :5])
@@ -52,6 +63,7 @@ _SCRIPT = textwrap.dedent("""
                                    core_budget=256), engine,
                        two_tier(engine))
     fstate = fx.init_state(D)
+    assert_placed(fx, fstate, jnp.zeros((E, BATCH, D)), jnp.zeros((E, BATCH)))
     oracle = [StreamExecutor(scfg, engine, two_tier(engine))
               for _ in range(E)]
     ostates = [ox.init_state(D) for ox in oracle]
@@ -78,6 +90,8 @@ _SCRIPT = textwrap.dedent("""
                 np.asarray(fout.outputs[e]), np.asarray(oo.outputs),
                 rtol=1e-6, atol=1e-6)
     assert fx.trace_count == 1, fx.trace_count
+    # ... and one compiled executable: no tick compiled without a trace
+    assert fx._jstep._cache_size() == 1, fx._jstep._cache_size()
     md = fstate.metrics.as_dict()
     for e in range(E):
         om = ostates[e].metrics.as_dict()
@@ -179,6 +193,30 @@ _SCRIPT = textwrap.dedent("""
                                    rtol=1e-6, atol=1e-6)
     assert fx1.trace_count == 1
     print("SINGLE_OK")
+
+    # --- 5. a remesh places on the new mesh: one re-trace each way ----
+    def feed(t, e):
+        items = rng.standard_normal((e, BATCH, D)).astype(np.float32)
+        ts = np.tile((8 + t) * BATCH + np.arange(BATCH, dtype=np.float32),
+                     (e, 1))
+        return jnp.asarray(items), jnp.asarray(ts)
+
+    devs = jax.devices()
+    fstate, _ = fx.remesh(fstate, devs[:7], keep=list(range(7)),
+                          fold_counters={7: 6})
+    assert_placed(fx, fstate, *feed(0, 7))
+    for t in range(2):
+        fstate, _ = fx.step(fstate, *feed(t, 7))
+    assert fx.trace_count == 2 <= 1 + fx.remeshes, fx.trace_count
+    # a remesh builds the step anew: one executable on the new mesh
+    assert fx._jstep._cache_size() == 1, fx._jstep._cache_size()
+    fstate, _ = fx.remesh(fstate, devs, keep=list(range(7)) + [None])
+    assert_placed(fx, fstate, *feed(2, 8))
+    for t in range(2, 4):
+        fstate, _ = fx.step(fstate, *feed(t, 8))
+    assert fx.trace_count == 3 <= 1 + fx.remeshes, fx.trace_count
+    assert fx._jstep._cache_size() == 1, fx._jstep._cache_size()
+    print("REMESH_OK")
 """)
 
 
@@ -196,3 +234,4 @@ def test_fleet_executor_oracle_and_budget(n, tmp_path):
     assert "BUDGET_OK" in out.stdout
     assert "WATERMARK_OK" in out.stdout
     assert "SINGLE_OK" in out.stdout
+    assert "REMESH_OK" in out.stdout

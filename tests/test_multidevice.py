@@ -13,7 +13,7 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core import routing, sfc
     from repro.core.overlay import Overlay
     from repro.runtime.compression import cross_pod_allreduce, init_errors

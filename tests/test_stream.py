@@ -285,6 +285,8 @@ def test_executor_single_trace_and_conservation(rng):
     state, out, _ = _feed(ex, state, rng, 10)
     m = state.metrics
     assert ex.trace_count == 1
+    # one compiled executable too: no tick compiled without a new trace
+    assert ex._jstep._cache_size() == 1
     assert int(m.steps) == 10
     assert int(m.items_offered) == 320
     assert int(m.items_accepted) + int(m.items_rejected) \
