@@ -4,10 +4,11 @@ cost accounting, exporters.
 The measurement substrate the perf roadmap is judged against — six
 pieces, each usable alone:
 
-* ``obs.trace`` — host-side span tracer (Chrome-trace/Perfetto export)
-  with JAX profiler hooks (``TraceAnnotation``/``StepTraceAnnotation``)
-  so host phases and device stages line up on one timeline
-  (``DEVICE_STAGES`` is the canonical ``named_scope`` taxonomy).
+* ``obs.trace`` — host-side span tracer (span tree per tick, wall and
+  thread CPU time; Chrome-trace/Perfetto export) with JAX profiler
+  hooks (``TraceAnnotation``/``StepTraceAnnotation``) so host phases
+  and device stages line up on one timeline (PERF.md, section 3, lists
+  the spans and the ``named_scope`` stages).
 * ``obs.events`` — structured JSONL event log for the control plane:
   every decision (budget resize, health change, leave/join, remesh,
   backup replay, drains, SLO breach/recover) as one typed record with
@@ -55,4 +56,4 @@ from repro.obs.latency import (  # noqa: F401
     lineage_update,
 )
 from repro.obs.slo import SLO, SloEvaluator, SloStatus  # noqa: F401
-from repro.obs.trace import DEVICE_STAGES, NULL_TRACER, Tracer  # noqa: F401
+from repro.obs.trace import NULL_TRACER, Span, Tracer  # noqa: F401

@@ -11,8 +11,8 @@ Two layers:
   compiled HLO text: every op carries its ``op_name`` metadata with the
   full ``named_scope`` path (``.../obs:window/reduce``), so ops and
   their result bytes are summed per ``obs:*`` stage
-  (:data:`repro.obs.trace.DEVICE_STAGES`; the innermost scope wins —
-  scopes nest).  Result bytes undercount true traffic (operand reads
+  (the stages of the layer table in PERF.md, section 3; the innermost
+  scope wins — scopes nest).  Result bytes undercount true traffic (operand reads
   are not re-counted) — treat stage bytes as a *relative* ranking; the
   executable-level total is the roofline-grade number.
 * :func:`roofline` turns (flops, bytes, measured seconds) into achieved

@@ -20,9 +20,9 @@ micro-batch row is stamped with its ingest wall time (relative to the
 executor's epoch, an f32 column in the ring row), and each tick
 bucket-increments one histogram row per :data:`LINEAGE_STAGES` stage —
 queueing delay, window residency, the two escalation hops, and
-end-to-end — via :func:`histogram_update_batch` (a vectorized
-mask-validated scatter-add: fixed shapes, donated operand, zero added
-recompiles).  Latencies are quantized to the tick: every stage a
+end-to-end — via :func:`histogram_update_batch` (a mask-validated
+compare-and-reduce with no loop and no scatter: fixed shapes, donated
+operand, zero added recompiles).  Latencies are quantized to the tick: every stage a
 record passes inside one tick shares the tick's dispatch timestamp, so
 sub-tick stage latencies land in bucket 0 ("< 1 tick") and the
 distribution's signal is cross-tick residency — ring backpressure,
@@ -60,6 +60,30 @@ def histogram_init(edges: np.ndarray = DEFAULT_EDGES) -> jnp.ndarray:
     return jnp.zeros((len(edges) + 1,), jnp.int32)
 
 
+def bucket_counts(values, mask, edges: np.ndarray = DEFAULT_EDGES
+                  ) -> jnp.ndarray:
+    """Per-bucket counts ``[len(edges) + 1]`` int32 of the masked-in
+    ``values`` (traced; any shape, flattened).  A value lands in the
+    bucket ``searchsorted(edges, value, side="left")`` names: bucket
+    ``i`` holds ``(edges[i-1], edges[i]]`` and the last bucket what lies
+    above ``edges[-1]`` (NaN included).
+
+    Loop- and scatter-free: one fused compare-and-reduce gives, per
+    edge, how many masked-in values lie at or below it (the cumulative
+    counts), and their differences are the buckets — where a
+    ``searchsorted`` lowers to a ``while`` over every value and the
+    increment to a scatter."""
+    e = jnp.asarray(edges, jnp.float32)[:, None]
+    # [edges, values]: the values run along the lanes, so each edge's
+    # count is a lane-dense compare and a reduce along the minor axis
+    v = jnp.reshape(jnp.asarray(values, jnp.float32), (1, -1))
+    m = jnp.reshape(jnp.asarray(mask, bool), (1, -1))
+    at_or_below = jnp.sum((m & (v <= e)).astype(jnp.int32), axis=1)
+    total = jnp.sum(m.astype(jnp.int32))[None]
+    cum = jnp.concatenate([at_or_below, total])
+    return cum - jnp.concatenate([jnp.zeros((1,), jnp.int32), cum[:-1]])
+
+
 def histogram_update(counts: jnp.ndarray, value,
                      edges: np.ndarray = DEFAULT_EDGES) -> jnp.ndarray:
     """Bucket-increment ``counts`` with one sample (traced; fixed
@@ -67,8 +91,8 @@ def histogram_update(counts: jnp.ndarray, value,
     executors feed the previous step's wall time, which is 0.0 before
     the first step (a missing measurement, not a fast step)."""
     value = jnp.asarray(value, jnp.float32)
-    idx = jnp.searchsorted(jnp.asarray(edges, jnp.float32), value)
-    return counts.at[idx].add(jnp.where(value > 0.0, 1, 0).astype(counts.dtype))
+    return counts + bucket_counts(value, value > 0.0,
+                                  edges).astype(counts.dtype)
 
 
 def histogram_update_batch(counts: jnp.ndarray, values, mask,
@@ -82,10 +106,9 @@ def histogram_update_batch(counts: jnp.ndarray, values, mask,
     tick), so masked-in values are clamped up to the first bucket —
     same-tick samples count in bucket 0 ("<= 1 µs", i.e. "< 1 tick" at
     the lineage's tick-quantized resolution) instead of vanishing."""
-    e = jnp.asarray(edges, jnp.float32)
-    v = jnp.maximum(jnp.asarray(values, jnp.float32), e[0] * 0.5)
-    idx = jnp.searchsorted(e, v)
-    return counts.at[idx].add(jnp.asarray(mask).astype(counts.dtype))
+    v = jnp.maximum(jnp.asarray(values, jnp.float32),
+                    jnp.float32(edges[0] * 0.5))
+    return counts + bucket_counts(v, mask, edges).astype(counts.dtype)
 
 
 def histogram_merge(a, b):
@@ -110,12 +133,20 @@ def lineage_update(bank: jnp.ndarray, samples: dict,
                    edges: np.ndarray = DEFAULT_EDGES) -> jnp.ndarray:
     """Batch-update stage rows of a lineage bank (traced).  ``samples``
     maps stage names (:data:`LINEAGE_STAGES`) to ``(values, mask)``
-    pairs; stages absent this tick keep their counts unchanged."""
-    for name, (values, mask) in samples.items():
-        i = LINEAGE_STAGES.index(name)     # ValueError -> typo'd stage
-        bank = bank.at[i].set(
-            histogram_update_batch(bank[i], values, mask, edges))
-    return bank
+    pairs; stages absent this tick keep their counts unchanged.  A key
+    may be a tuple of stage names whose rows take one measurement:
+    it is bucketed once and added to each."""
+    zero = jnp.zeros(bank.shape[-1:], bank.dtype)
+    rows = {}
+    for key, (values, mask) in samples.items():
+        names = key if isinstance(key, tuple) else (key,)
+        unknown = [n for n in names if n not in LINEAGE_STAGES]
+        if unknown:
+            raise ValueError(f"unknown lineage stages {unknown}; known: "
+                             f"{LINEAGE_STAGES}")
+        rows.update(dict.fromkeys(
+            names, histogram_update_batch(zero, values, mask, edges)))
+    return bank + jnp.stack([rows.get(n, zero) for n in LINEAGE_STAGES])
 
 
 def lineage_percentiles(bank, qs=(50, 95, 99),

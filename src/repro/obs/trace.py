@@ -1,36 +1,49 @@
 """Host-side span tracer with JAX profiler hooks and Chrome-trace export.
 
-``Tracer`` records lightweight wall-clock spans around the host phases
-of a stream tick (inject -> dispatch -> device execute -> control ->
-drain).  Each span doubles as a ``jax.profiler.TraceAnnotation``, so
-when a JAX profiler capture is live (``with tracer.profile(logdir)``)
-the same spans appear on the host timeline of the XLA trace viewer —
-host/device overlap and the dispatch-vs-execute split become *visible*
-next to the device ops, which carry their own stage names via
-``jax.named_scope`` (see ``stream.executor``/``stream.fleet``).
+``Tracer`` records lightweight spans around the host phases of a stream
+tick (dispatch -> device execute -> control).  Each span keeps its wall
+times on the ``perf_counter`` clock, the thread's CPU seconds
+(``time.thread_time``) at entry and exit, and its parent: the innermost
+span open on the same thread when it started.  So a tick is a span tree
+(``fleet.step`` > ``fleet.dispatch`` > ``fleet.operands``, ...), and a
+span's wall time less its CPU time is the time its thread spent off the
+CPU: waiting for the GIL, a lock or a blocking copy.
+
+Each span doubles as a ``jax.profiler.TraceAnnotation``, so when a JAX
+profiler capture is live (``with tracer.profile(logdir)``) the same
+spans appear on the host timeline of the XLA trace, next to the device
+ops, which carry their own stage names via ``jax.named_scope`` (the
+layer table of PERF.md, section 3, lists them).
 
 Two export paths:
 
 * :meth:`Tracer.export_chrome_trace` — self-contained Chrome trace
   JSON (open in ``chrome://tracing`` or https://ui.perfetto.dev) from
-  the host spans alone; zero dependencies, works headless.
+  the host spans alone; zero dependencies, works headless.  After a
+  :meth:`Tracer.profile` capture its timestamps are on the capture's
+  clock (microseconds since the capture started), so the file overlays
+  the device trace; CPU time rides in the format's ``tts``/``tdur``.
 * :meth:`Tracer.profile` — wraps ``jax.profiler.trace``: the full XLA
   profile (device ops + these host annotations) lands in ``logdir`` as
   a TensorBoard/Perfetto trace.
 
-Overhead discipline: a disabled tracer (``NULL_TRACER``) costs one
-attribute lookup and a pre-built null context per span — safe to leave
-in the hot path; an enabled tracer costs two clock reads and one list
+Overhead discipline: a disabled tracer (``NULL_TRACER``) hands back a
+pre-built null context per span and reads no clock — safe to leave in
+the hot path; an enabled tracer costs four clock reads and one list
 append per span.  Nothing here touches traced code: instrumentation
-adds **zero** recompiles (the fleet tests assert their trace bounds
+adds **zero** recompiles (the executor tests assert their trace bounds
 with tracing on).
 """
 from __future__ import annotations
 
 import contextlib
+import glob
+import itertools
 import json
+import os
 import threading
 import time
+from typing import NamedTuple
 
 import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
@@ -38,46 +51,68 @@ from jax.profiler import trace as _jax_trace
 
 _NULL_CTX = contextlib.nullcontext()
 
-#: Canonical ``jax.named_scope`` stage labels of the traced tick, in
-#: hot-path order (single-device prefix, then the fleet-only stages).
-#: ``obs.costmodel`` attributes HLO ops to these by compiled-metadata
-#: ``op_name`` substring match; keep in sync with the executors.
-DEVICE_STAGES = (
-    "obs:ingest", "obs:watermark", "obs:window", "obs:lineage",
-    "obs:rules", "obs:pipeline", "obs:metrics",
-    "obs:fleet_watermark", "obs:edge_stages", "obs:exchange_core",
-    "obs:all_to_all_out", "obs:fog_compact", "obs:all_to_all_region",
-    "obs:core_compute", "obs:all_to_all_back", "obs:core_commit",
-    "obs:latency",
-)
+
+class Span(NamedTuple):
+    """One closed span.  The first five fields are the original record
+    (seconds on the ``perf_counter`` clock); ``id`` numbers spans in the
+    order they opened, ``parent`` is the ``id`` of the innermost span
+    open on the same thread at entry (None at the root), and
+    ``cpu0``/``cpu1`` are the thread's CPU seconds at entry and exit."""
+    name: str
+    t0: float
+    t1: float
+    tid: int
+    args: dict
+    id: int
+    parent: int | None
+    cpu0: float
+    cpu1: float
 
 
 class Tracer:
     """Accumulates named host spans; thread-safe appends.
 
-    Spans nest naturally in Chrome trace rendering (same thread id,
-    containing timestamps).  ``args`` ride along into the trace
-    viewer's detail pane and into :meth:`stage_percentiles` grouping.
+    ``args`` ride along into the trace viewer's detail pane and into
+    :meth:`stage_percentiles` grouping.
     """
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
-        self._spans: list[tuple[str, float, float, int, dict]] = []
+        self._spans: list[Span] = []
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
+        self._open = threading.local()       # per-thread stack of open ids
+        self._ids = itertools.count()
+        # export origin on the perf_counter clock: tracer creation, or
+        # the start of the last profile() capture
+        self._origin = time.perf_counter()
 
     # -- recording ---------------------------------------------------------
     @contextlib.contextmanager
     def _span(self, name: str, args: dict):
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        sid = next(self._ids)
+        parent = stack[-1] if stack else None
+        stack.append(sid)
+        # the wall clock is read right inside the annotation, so the
+        # span and its mirror in a profiler capture start together; the
+        # CPU clock (a system call, where the thread may be switched
+        # out) is read outside both
+        c0 = time.thread_time()
+        annotation = TraceAnnotation(name)
+        annotation.__enter__()
         t0 = time.perf_counter()
         try:
-            with TraceAnnotation(name):
-                yield self
+            yield self
         finally:
             t1 = time.perf_counter()
+            annotation.__exit__(None, None, None)
+            c1 = time.thread_time()
+            stack.pop()
             with self._lock:
-                self._spans.append((name, t0, t1,
-                                    threading.get_ident(), args))
+                self._spans.append(Span(name, t0, t1, threading.get_ident(),
+                                        args, sid, parent, c0, c1))
 
     def span(self, name: str, **args):
         """Context manager: record ``name`` around the enclosed block
@@ -97,10 +132,23 @@ class Tracer:
     def profile(self, logdir: str):
         """Capture a full XLA profile (device ops + host annotations)
         to ``logdir`` while the context is open.  View with
-        TensorBoard's profile plugin or https://ui.perfetto.dev."""
+        TensorBoard's profile plugin or https://ui.perfetto.dev.
+        Afterwards the Chrome export is on this capture's clock."""
         if not self.enabled:
             return _NULL_CTX
-        return _jax_trace(logdir)
+        return self._profile(logdir)
+
+    @contextlib.contextmanager
+    def _profile(self, logdir: str):
+        before = set(_xplanes(logdir))
+        unix_start = time.time_ns()
+        with _jax_trace(logdir):
+            unix_minus_perf = _unix_minus_perf()
+            yield
+        new = sorted(set(_xplanes(logdir)) - before, key=os.path.getmtime)
+        if new:
+            unix_start = _capture_start_ns(new[-1]) or unix_start
+        self._origin = unix_start / 1e9 - unix_minus_perf
 
     def clear(self) -> None:
         with self._lock:
@@ -108,9 +156,10 @@ class Tracer:
 
     # -- reading -----------------------------------------------------------
     @property
-    def spans(self) -> list:
-        """(name, t_start, t_end, thread_id, args) tuples, seconds on
-        the ``perf_counter`` clock."""
+    def spans(self) -> list[Span]:
+        """Closed spans in the order they closed (a child before its
+        parent): :class:`Span` tuples whose first five fields are
+        (name, t_start, t_end, thread_id, args)."""
         with self._lock:
             return list(self._spans)
 
@@ -119,8 +168,8 @@ class Tracer:
         ``{name: {count, mean_us, total_us, p50_us, p95_us, p99_us}}``
         — the host-side per-stage latency breakdown."""
         by_name: dict[str, list[float]] = {}
-        for name, t0, t1, _, _ in self.spans:
-            by_name.setdefault(name, []).append((t1 - t0) * 1e6)
+        for sp in self.spans:
+            by_name.setdefault(sp.name, []).append((sp.t1 - sp.t0) * 1e6)
         out = {}
         for name, durs in sorted(by_name.items()):
             d = np.asarray(durs)
@@ -134,15 +183,19 @@ class Tracer:
 
     # -- export ------------------------------------------------------------
     def to_chrome_trace(self) -> dict:
-        """Chrome trace JSON object (``traceEvents`` complete events,
-        microsecond timestamps relative to tracer creation)."""
+        """Chrome trace JSON object (``traceEvents`` complete events):
+        ``ts``/``dur`` in microseconds since tracer creation, or since
+        the start of the last :meth:`profile` capture, on that capture's
+        clock; ``tts``/``tdur`` the thread's CPU microseconds."""
         events = []
-        for name, t0, t1, tid, args in self.spans:
+        for sp in self.spans:
             events.append({
-                "name": name, "ph": "X", "pid": 1, "tid": tid,
-                "ts": (t0 - self._t0) * 1e6,
-                "dur": (t1 - t0) * 1e6,
-                "args": {k: _plain(v) for k, v in args.items()},
+                "name": sp.name, "ph": "X", "pid": 1, "tid": sp.tid,
+                "ts": (sp.t0 - self._origin) * 1e6,
+                "dur": (sp.t1 - sp.t0) * 1e6,
+                "tts": sp.cpu0 * 1e6,
+                "tdur": (sp.cpu1 - sp.cpu0) * 1e6,
+                "args": {k: _plain(v) for k, v in sp.args.items()},
             })
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -152,6 +205,29 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_chrome_trace(), f)
         return path
+
+
+def _unix_minus_perf() -> float:
+    """Unix seconds (the profiler's clock) less ``perf_counter``
+    seconds."""
+    return time.time_ns() / 1e9 - time.perf_counter()
+
+
+def _xplanes(logdir: str) -> list[str]:
+    return glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                     recursive=True)
+
+
+def _capture_start_ns(path: str) -> int | None:
+    """Unix nanoseconds at which the capture in ``path`` started: the
+    origin of its event times (``profile_start_time``)."""
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_file(path).planes:
+        for key, value in plane.stats:
+            if key == "profile_start_time":
+                return int(value)
+    return None
 
 
 def _plain(v):

@@ -447,7 +447,7 @@ class StreamExecutor:
         self.pipeline = pipeline
         self._traces = 0
         self._budget = None            # dynamic core budget (traced operand)
-        self.last_step_seconds = 0.0   # host wall time of the last step()
+        self.last_step_seconds = 0.0   # host wall time of the last dispatch
         # observability: host span tracer (default disabled — near-zero
         # cost) + on-device step-latency histogram + per-stage lineage
         # bank.  Both ride the step as fixed-shape donated operands (the
@@ -486,7 +486,7 @@ class StreamExecutor:
 
     def set_tracer(self, tracer) -> None:
         """Install an ``obs.Tracer`` for host-span instrumentation of
-        ``step()`` (dispatch span + JAX profiler step annotation).
+        ``step()`` (its span tree + JAX profiler step annotation).
         Tracing changes no traced shapes — zero recompiles."""
         self.tracer = tracer
 
@@ -583,11 +583,11 @@ class StreamExecutor:
                 jnp.sum(result.dropped.astype(jnp.int32)), overflow)
             lat_hist = OL.histogram_update(lat_hist, last_dt)
         with jax.named_scope("obs:lineage"):
-            w_lat = now - ing.w_birth
+            # window residency and end-to-end are one measurement here
+            # (everything commits in-tick): bucketed once, added to both
             lineage = OL.lineage_update(lineage, {
                 "queueing": (ing.q_lat, ing.q_mask),
-                "window": (w_lat, ing.emit),
-                "e2e": (w_lat, ing.emit),
+                ("window", "e2e"): (now - ing.w_birth, ing.emit),
             })
         new_state = StreamState(
             rb=ing.rb, carry=ing.carry, carry_valid=ing.carry_valid,
@@ -623,32 +623,44 @@ class StreamExecutor:
         construction — the same f32 caveat applies after ~2^24 seconds
         (about six months of uptime; restart the epoch before then).
 
-        ``last_step_seconds`` records the host wall time of the call —
-        dispatch time unless the caller synchronizes, the full step if
-        it does (the control plane feeds these into its straggler
-        detector; real deployments substitute per-device telemetry).
-        The previous step's wall time also feeds the on-device latency
-        histogram (``latency_percentiles()``) as a traced operand —
-        except after a (re)trace, whose wall time is compile time: that
-        sample is withheld (``warmup_excluded``) so one warmup tick can
-        never masquerade as a million-microsecond p99."""
+        ``last_step_seconds`` records the host wall time of the
+        dispatch only (the bounds of the ``stream.dispatch`` span): jit
+        dispatch is async, so device execution is not in it, except
+        where the dispatch waits for the previous tick's buffers (the
+        control plane feeds these into its straggler detector; real
+        deployments substitute per-device telemetry).  The previous
+        step's wall time also feeds the on-device latency histogram
+        (``latency_percentiles()``) as a traced operand — except after a
+        (re)trace, whose wall time is compile time: that sample is
+        withheld (``warmup_excluded``) so one warmup tick can never
+        masquerade as a million-microsecond p99.
+
+        With a tracer installed a call is the span tree ``stream.step``
+        > ``stream.dispatch`` > ``stream.operands`` (the scalar operands
+        put on the device), ``stream.call`` (the jit call alone).
+        """
         self._step_num += 1
-        feed = 0.0 if self._skip_feed else self.last_step_seconds
-        if self._skip_feed and self.last_step_seconds > 0.0:
-            self.warmup_excluded += 1
-        traces_before = self._traces
-        t0 = time.perf_counter()
-        with self.tracer.step_annotation("stream_step", self._step_num), \
-                self.tracer.span("stream.dispatch", step=self._step_num):
-            state, out, self._lat_hist, self._lineage = self._jstep(
-                state, items, ts,
-                jnp.asarray(self._effective_budget(), jnp.int32),
-                self._lat_hist, self._lineage,
-                jnp.asarray(feed, jnp.float32),
-                jnp.asarray(time.perf_counter() - self._t0, jnp.float32),
-                jnp.asarray(mode, jnp.int32))
-        self.last_step_seconds = time.perf_counter() - t0
-        self._skip_feed = self._traces > traces_before
+        tracer = self.tracer
+        with tracer.span("stream.step", step=self._step_num):
+            feed = 0.0 if self._skip_feed else self.last_step_seconds
+            if self._skip_feed and self.last_step_seconds > 0.0:
+                self.warmup_excluded += 1
+            traces_before = self._traces
+            t0 = time.perf_counter()
+            with tracer.step_annotation("stream_step", self._step_num), \
+                    tracer.span("stream.dispatch", step=self._step_num):
+                with tracer.span("stream.operands"):
+                    budget = jnp.asarray(self._effective_budget(), jnp.int32)
+                    last_dt = jnp.asarray(feed, jnp.float32)
+                    now = jnp.asarray(time.perf_counter() - self._t0,
+                                      jnp.float32)
+                    mode = jnp.asarray(mode, jnp.int32)
+                with tracer.span("stream.call"):
+                    state, out, self._lat_hist, self._lineage = self._jstep(
+                        state, items, ts, budget, self._lat_hist,
+                        self._lineage, last_dt, now, mode)
+            self.last_step_seconds = time.perf_counter() - t0
+            self._skip_feed = self._traces > traces_before
         return state, out
 
     def run(self, state: StreamState,

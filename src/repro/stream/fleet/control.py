@@ -431,7 +431,9 @@ class FleetController:
         budget on the executor for the next data tick.  With an
         ``event_log`` installed, every actuation (health-mask change,
         budget resize) lands as a typed JSONL record; with a ``tracer``
-        the whole tick is one host span."""
+        the tick is the span ``control.tick`` > ``control.pull`` (the
+        one host pull of the counters), ``control.decide`` (detectors,
+        budget policies, actuation, the SLO lane)."""
         with self.tracer.span("control.tick", tick=self._ticks):
             decision = self._tick(state, step_times)
         self._ticks += 1
@@ -439,16 +441,24 @@ class FleetController:
 
     def _tick(self, state: FleetState,
               step_times: np.ndarray | None = None) -> ControlDecision:
-        ex = self.executor
-        e = ex.cfg.num_shards
         # one host pull for everything the loop needs
-        max_ts, esc_total, wm, rej_total, ded_total, drift_total = \
-            jax.device_get(
+        with self.tracer.span("control.pull", tick=self._ticks):
+            pulled = jax.device_get(
                 (state.shard.max_ts,
                  state.shard.metrics.windows_escalated,
                  state.watermark, state.shard.metrics.items_rejected,
                  state.shard.metrics.items_deduped,
                  state.shard.metrics.drift_counts))
+        with self.tracer.span("control.decide", tick=self._ticks):
+            return self._decide(state, pulled, step_times)
+
+    def _decide(self, state: FleetState, pulled: tuple,
+                step_times: np.ndarray | None) -> ControlDecision:
+        """Detectors, budget policies, actuation and the SLO lane on the
+        pulled counters."""
+        ex = self.executor
+        e = ex.cfg.num_shards
+        max_ts, esc_total, wm, rej_total, ded_total, drift_total = pulled
         max_ts = np.asarray(max_ts, np.float64)
         esc_total = np.asarray(esc_total, np.int64)
         escalated = esc_total - self._prev_escalated

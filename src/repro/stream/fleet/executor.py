@@ -523,8 +523,8 @@ class FleetExecutor:
         return self._remeshes
 
     def set_tracer(self, tracer) -> None:
-        """Install an ``obs.Tracer``: host spans around dispatch and
-        device execution + a JAX profiler step annotation per tick.
+        """Install an ``obs.Tracer``: the host span tree of ``step()``
+        (see there) + a JAX profiler step annotation per tick.
         Changes no traced shapes — zero recompiles."""
         self.tracer = tracer
 
@@ -741,13 +741,13 @@ class FleetExecutor:
         # lands where the latency is *experienced*, so pooling per
         # region shows each tier's receive-side distribution
         with jax.named_scope("obs:lineage"):
-            w_lat = now - ing.w_birth
+            # window and e2e are one measurement (the shard commits
+            # in-tick): bucketed once, added to both rows
             lin = OL.lineage_update(lin, {
                 "queueing": (ing.q_lat, ing.q_mask),
-                "window": (w_lat, ing.emit),
+                ("window", "e2e"): (now - ing.w_birth, ing.emit),
                 "hop1": (now - taps.hop1_birth, taps.hop1_mask),
                 "hop2": (now - taps.hop2_birth, taps.hop2_mask),
-                "e2e": (w_lat, ing.emit),
             })
 
         n_esc = jnp.sum(core_live.astype(jnp.int32))
@@ -817,65 +817,77 @@ class FleetExecutor:
         plane's wall-time straggler detector a signal a slow device
         never inflates.  Callers with real per-device telemetry can set
         ``measure_steps = False`` to skip the sync and keep host/device
-        overlap."""
-        if offered is None:
-            offered = jnp.ones(items.shape[:2], bool)
-        if replay is not None and mode is not None:
-            raise ValueError("pass either replay (bool shorthand) or "
-                             "mode (MODE_* codes), not both")
-        if replay is not None:
-            mode = np.where(np.asarray(replay, bool),
-                            SI.MODE_REPLAY, SI.MODE_LIVE).astype(np.int32)
-        if mode is None:
-            mode = np.zeros(self.cfg.num_shards, np.int32)
-        elif np.asarray(mode).any():
-            # batch-granular reprocessing precondition, enforced (silent
-            # window corruption otherwise, see README "Shard churn"):
-            # a per-tick-drained ring (N <= micro_batch; N is fixed by
-            # the trace, so replayed/backfilled rows can never linger in
-            # the ring past their lateness-exempt tick).  Sliding-carry
-            # configs are legal too, PROVIDED the control plane
-            # performed the mid-ring carry handoff
-            # (``FleetController.begin_replay_carry`` /
-            # ``end_replay_carry``): the departed stream's window carry
-            # rides on the backup's slot for the replay ticks, so the
-            # backup's own samples never smear into replayed windows.
-            if items.shape[1] > self.cfg.stream.micro_batch:
-                raise ValueError(
-                    f"replay/backfill needs a per-tick-drained ring: "
-                    f"offer size {items.shape[1]} > micro_batch "
-                    f"{self.cfg.stream.micro_batch} leaves reprocessed "
-                    "rows queued past their lateness-exempt tick")
+        overlap.
+
+        With a tracer installed a call is the span tree ``fleet.step``
+        > ``fleet.dispatch`` > ``fleet.operands`` (the host values put
+        on the device), ``fleet.call`` (the jit call alone); then
+        ``fleet.device_execute`` (the wait for the device) under
+        ``fleet.step``."""
         self._step_num += 1
-        # warmup exclusion: the previous tick's wall time is the
-        # histogram feed — unless that tick traced, in which case it
-        # measured compile+execute and would pollute the tail (the
-        # p99-vs-p95 cliff the BENCH baselines showed).  Feed 0.0
-        # instead (histogram_update skips non-positive) and count it
-        feed = 0.0 if self._skip_feed else self.last_step_seconds
-        if self._skip_feed and self.last_step_seconds > 0.0:
-            self.warmup_excluded += 1
-        traces_before = self._traces
-        t0 = time.perf_counter()
-        with self.tracer.step_annotation("fleet_tick", self._step_num):
-            with self.tracer.span("fleet.dispatch", step=self._step_num):
-                out, self._lat_hist, self._lineage = self._jstep(
-                    state, items, ts, jnp.asarray(offered, bool),
-                    jnp.asarray(mode, jnp.int32),
-                    jnp.asarray(self._healthy),
-                    jnp.asarray(self._active),
-                    jnp.asarray(self._budget, jnp.int32),
-                    jnp.asarray(self._region_budget, jnp.int32),
-                    self._lat_hist, self._lineage,
-                    jnp.asarray(feed, jnp.float32),
-                    jnp.asarray(time.perf_counter() - self._t0,
-                                jnp.float32))
-            if self.measure_steps:
-                with self.tracer.span("fleet.device_execute",
-                                      step=self._step_num):
-                    jax.block_until_ready(out)
-        self.last_step_seconds = time.perf_counter() - t0
-        self._skip_feed = self._traces > traces_before
+        tracer = self.tracer
+        with tracer.span("fleet.step", step=self._step_num):
+            if offered is None:
+                offered = jnp.ones(items.shape[:2], bool)
+            if replay is not None and mode is not None:
+                raise ValueError("pass either replay (bool shorthand) or "
+                                 "mode (MODE_* codes), not both")
+            if replay is not None:
+                mode = np.where(np.asarray(replay, bool),
+                                SI.MODE_REPLAY, SI.MODE_LIVE).astype(np.int32)
+            if mode is None:
+                mode = np.zeros(self.cfg.num_shards, np.int32)
+            elif np.asarray(mode).any():
+                # batch-granular reprocessing precondition, enforced
+                # (silent window corruption otherwise, see README "Shard
+                # churn"): a per-tick-drained ring (N <= micro_batch; N
+                # is fixed by the trace, so replayed/backfilled rows can
+                # never linger in the ring past their lateness-exempt
+                # tick).  Sliding-carry configs are legal too, PROVIDED
+                # the control plane performed the mid-ring carry handoff
+                # (``FleetController.begin_replay_carry`` /
+                # ``end_replay_carry``): the departed stream's window
+                # carry rides on the backup's slot for the replay ticks,
+                # so the backup's own samples never smear into replayed
+                # windows.
+                if items.shape[1] > self.cfg.stream.micro_batch:
+                    raise ValueError(
+                        f"replay/backfill needs a per-tick-drained ring: "
+                        f"offer size {items.shape[1]} > micro_batch "
+                        f"{self.cfg.stream.micro_batch} leaves reprocessed "
+                        "rows queued past their lateness-exempt tick")
+            # warmup exclusion: the previous tick's wall time is the
+            # histogram feed — unless that tick traced, in which case it
+            # measured compile+execute and would pollute the tail (the
+            # p99-vs-p95 cliff the BENCH baselines showed).  Feed 0.0
+            # instead (histogram_update skips non-positive) and count it
+            feed = 0.0 if self._skip_feed else self.last_step_seconds
+            if self._skip_feed and self.last_step_seconds > 0.0:
+                self.warmup_excluded += 1
+            traces_before = self._traces
+            t0 = time.perf_counter()
+            with tracer.step_annotation("fleet_tick", self._step_num):
+                with tracer.span("fleet.dispatch", step=self._step_num):
+                    with tracer.span("fleet.operands", step=self._step_num):
+                        ops = (jnp.asarray(offered, bool),
+                               jnp.asarray(mode, jnp.int32),
+                               jnp.asarray(self._healthy),
+                               jnp.asarray(self._active),
+                               jnp.asarray(self._budget, jnp.int32),
+                               jnp.asarray(self._region_budget, jnp.int32))
+                        last_dt = jnp.asarray(feed, jnp.float32)
+                        now = jnp.asarray(time.perf_counter() - self._t0,
+                                          jnp.float32)
+                    with tracer.span("fleet.call", step=self._step_num):
+                        out, self._lat_hist, self._lineage = self._jstep(
+                            state, items, ts, *ops, self._lat_hist,
+                            self._lineage, last_dt, now)
+                if self.measure_steps:
+                    with tracer.span("fleet.device_execute",
+                                     step=self._step_num):
+                        jax.block_until_ready(out)
+            self.last_step_seconds = time.perf_counter() - t0
+            self._skip_feed = self._traces > traces_before
         return out
 
     # -- true re-mesh (the device set changed) ------------------------------

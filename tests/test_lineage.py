@@ -113,6 +113,110 @@ def test_lineage_update_rejects_typo_stage():
         OL.lineage_update(bank, {"windwo": (jnp.zeros(4), jnp.ones(4, bool))})
 
 
+def _edge_cases(rng, n=65536):
+    """A batch of latencies with every hard case: values exactly on the
+    (float32) edges, zeros, negatives, values past the last edge, NaN
+    and inf, the rest log-spread over the whole range; about a third
+    of the rows masked out."""
+    e32 = DEFAULT_EDGES.astype(np.float32)
+    vals = np.exp(rng.uniform(np.log(1e-7), np.log(1e3), n)).astype(
+        np.float32)
+    k = len(e32)
+    vals[:k] = e32                                   # exactly on edges
+    vals[k:2 * k] = np.nextafter(e32, np.float32(np.inf))   # just above
+    vals[2 * k:2 * k + 64] = 0.0
+    vals[2 * k + 64:2 * k + 128] = -1.0
+    vals[2 * k + 128:2 * k + 192] = 5e2                    # overflow
+    vals[2 * k + 192:2 * k + 196] = [np.nan, np.inf, -np.inf, 1e30]
+    mask = rng.random(n) < 0.67
+    mask[:2 * k + 196] = True
+    return vals, mask
+
+
+def test_histogram_update_batch_65536_rows_bit_identical(rng):
+    """The loop-free bucketing equals numpy's ``searchsorted`` (left
+    side, float32 edges, clamped at ``edges[0] / 2``) and the
+    ``searchsorted`` + scatter-add formulation it replaced, on one
+    tick's worth of rows, added onto existing counts."""
+    vals, mask = _edge_cases(rng)
+    e32 = DEFAULT_EDGES.astype(np.float32)
+    start = rng.integers(0, 1000, len(DEFAULT_EDGES) + 1).astype(np.int32)
+    got = np.asarray(jax.jit(OL.histogram_update_batch)(
+        jnp.asarray(start), vals, mask))
+    clamped = np.maximum(vals, e32[0] * np.float32(0.5))
+    ref = start.astype(np.int64) + np.bincount(
+        np.searchsorted(e32, clamped[mask], side="left"),
+        minlength=len(start))
+    np.testing.assert_array_equal(got, ref)
+    e = jnp.asarray(DEFAULT_EDGES, jnp.float32)
+    old = jnp.asarray(start).at[jnp.searchsorted(
+        e, jnp.maximum(jnp.asarray(vals), e[0] * 0.5))].add(
+            jnp.asarray(mask).astype(jnp.int32))
+    np.testing.assert_array_equal(got, np.asarray(old))
+
+
+def test_histogram_update_scalar_on_edges():
+    """The one-sample update buckets exactly like ``searchsorted`` and
+    still skips non-positive and NaN samples."""
+    e32 = DEFAULT_EDGES.astype(np.float32)
+    upd = jax.jit(OL.histogram_update)
+    counts = OL.histogram_init()
+    ref = np.zeros(len(e32) + 1, np.int64)
+    for v in list(e32[::7]) + [e32[3] * 1.01, 0.0, -2.0, np.nan, 1e4]:
+        counts = upd(counts, jnp.float32(v))
+        if v > 0:
+            ref[np.searchsorted(e32, np.float32(v))] += 1
+    np.testing.assert_array_equal(np.asarray(counts), ref)
+
+
+def test_lineage_update_buckets_a_stage_group_once(rng):
+    """A tuple of stages takes one measurement into each of its rows;
+    absent stages keep their counts, and a typo'd name in a group is
+    refused."""
+    vals, mask = _edge_cases(rng, n=4096)
+    bank = jnp.asarray(_rand_bank(rng).astype(np.int32))
+    w = (vals[:1024], mask[:1024])
+    got = np.asarray(OL.lineage_update(bank, {
+        "queueing": (vals, mask), ("window", "e2e"): w}))
+    want = np.asarray(bank).copy()
+    for name, (v, m) in (("queueing", (vals, mask)), ("window", w),
+                         ("e2e", w)):
+        i = LINEAGE_STAGES.index(name)
+        want[i] = np.asarray(OL.histogram_update_batch(
+            jnp.asarray(want[i]), v, m))
+    np.testing.assert_array_equal(got, want)
+    with pytest.raises(ValueError):
+        OL.lineage_update(bank, {("window", "e2f"): w})
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["staged", "fused"])
+def test_compiled_step_lineage_has_no_loop_or_scatter(fused):
+    """The lineage histograms compile to no ``while`` and no scatter:
+    the compiled one-chip step, at a small size on the CPU, has neither
+    under ``obs:lineage``."""
+    import re
+
+    from repro.core import pipeline as pipe
+    from repro.core import rules
+    from repro.stream import StreamConfig, StreamExecutor
+
+    engine = rules.RuleEngine([
+        rules.threshold_rule("hot", 0, ">=", 0.5, rules.C_SEND_CORE)])
+    edge_fn = lambda p, b: (b, b[:, :5])  # noqa: E731
+    scfg = StreamConfig(micro_batch=64, window=16, stride=16, capacity=128,
+                        fused=fused)
+    ex = StreamExecutor(scfg, engine,
+                        pipe.two_tier_pipeline(edge_fn, edge_fn, engine))
+    text = ex.lower(ex.init_state(3), jnp.zeros((64, 3)),
+                    jnp.zeros((64,))).compile().as_text()
+    lineage = [ln for ln in text.splitlines()
+               if re.search(r'op_name="[^"]*obs:lineage', ln)]
+    assert lineage                      # the scope is in the module
+    bad = [ln.strip()[:120] for ln in lineage
+           if re.search(r"= .*?\b(while|scatter)\(", ln)]
+    assert not bad, bad
+
+
 # --- warmup exclusion (regression: compile-polluted p99) ------------------
 
 def _stream_executor(micro_batch=32, window=16, stride=16, capacity=128):

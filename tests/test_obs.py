@@ -233,6 +233,141 @@ def test_null_tracer_records_nothing():
     assert not NULL_TRACER.enabled
 
 
+def test_span_parents_and_offcpu_time():
+    """A span records the innermost open span of its thread as parent,
+    and a span that sleeps shows the sleep as time off the CPU."""
+    import threading
+    import time
+
+    tr = Tracer()
+    with tr.span("outer", late=7):
+        with tr.span("sleep"):
+            time.sleep(0.02)
+
+        def other():
+            with tr.span("other"):
+                pass
+
+        side = threading.Thread(target=other)
+        side.start()
+        side.join()
+    by = {sp.name: sp for sp in tr.spans}
+    assert by["outer"].parent is None
+    assert by["other"].parent is None          # another thread's root
+    assert by["sleep"].parent == by["outer"].id
+    assert by["outer"].args == {"late": 7}
+    sleep = by["sleep"]
+    assert (sleep.t1 - sleep.t0) - (sleep.cpu1 - sleep.cpu0) >= 0.015, sleep
+    assert sleep.cpu1 - sleep.cpu0 < 0.005
+    assert all(sp[:5] == (sp.name, sp.t0, sp.t1, sp.tid, sp.args)
+               for sp in tr.spans)
+    ev = {e["name"]: e for e in tr.to_chrome_trace()["traceEvents"]}
+    assert ev["sleep"]["tdur"] == pytest.approx(
+        (sleep.cpu1 - sleep.cpu0) * 1e6)
+    assert ev["sleep"]["dur"] - ev["sleep"]["tdur"] >= 15e3
+    assert ev["outer"]["args"] == {"late": 7}
+
+
+def test_null_tracer_reads_no_clock(monkeypatch, rng):
+    """With the disabled tracer a tick reads the clock as it always
+    did (start, ``now``, end) and the tracer itself reads none."""
+    import time as _time
+
+    from repro.obs import trace as OT
+    from repro.stream import executor as SE
+
+    calls = {"trace": 0, "step": 0}
+
+    class Clock:
+        def __init__(self, key):
+            self.key = key
+
+        def __getattr__(self, name):
+            return getattr(_time, name)
+
+        def perf_counter(self):
+            calls[self.key] += 1
+            return _time.perf_counter()
+
+        def thread_time(self):
+            calls[self.key] += 1
+            return _time.thread_time()
+
+    ex, state = _stream_executor()
+    items = jnp.asarray(rng.standard_normal((32, 3)), jnp.float32)
+    state, _ = ex.step(state, items, jnp.arange(32, dtype=jnp.float32))
+    monkeypatch.setattr(OT, "time", Clock("trace"))
+    monkeypatch.setattr(SE, "time", Clock("step"))
+    ex.step(state, items, 32 + jnp.arange(32, dtype=jnp.float32))
+    assert calls == {"trace": 0, "step": 3}
+
+
+def test_profile_export_on_the_capture_clock(tmp_path):
+    """After a ``Tracer.profile()`` capture the Chrome export is on the
+    capture's clock: each span starts within 2 ms of its
+    ``TraceAnnotation`` in the ``.xplane.pb``."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    jax.block_until_ready(jnp.ones(4) + 1)
+    with tr.profile(str(tmp_path)):
+        for i in range(4):
+            with tr.span(f"probe{i}"):
+                with tr.span(f"inner{i}"):
+                    time.sleep(0.005)
+            time.sleep(0.01)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    marks = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("probe", "inner")):
+                    marks[ev.name] = ev.start_ns
+    events = tr.to_chrome_trace()["traceEvents"]
+    assert {e["name"] for e in events} == set(marks) and len(marks) == 8
+    for e in events:
+        assert abs(e["ts"] * 1e3 - marks[e["name"]]) < 2e6, (e, marks)
+
+
+def test_stream_executor_span_tree(rng):
+    """One traced tick is the tree stream.step > stream.dispatch >
+    (stream.operands, stream.call), and tracing adds no trace."""
+    ex, state = _stream_executor()
+    tr = Tracer()
+    ex.set_tracer(tr)
+    quiet, qstate = _stream_executor()
+    for i in range(3):
+        items = jnp.asarray(rng.standard_normal((32, 3)), jnp.float32)
+        ts = jnp.asarray(i * 32 + np.arange(32), jnp.float32)
+        state, out = ex.step(state, items, ts)
+        qstate, _ = quiet.step(qstate, items, ts)
+        jax.block_until_ready(out)
+    assert ex.trace_count == quiet.trace_count == 1
+    spans = tr.spans
+    by_id = {sp.id: sp for sp in spans}
+
+    def parent(sp):
+        return by_id[sp.parent].name if sp.parent is not None else None
+
+    assert [sp.name for sp in spans].count("stream.step") == 3
+    for sp in spans:
+        assert parent(sp) == {"stream.step": None,
+                              "stream.dispatch": "stream.step",
+                              "stream.operands": "stream.dispatch",
+                              "stream.call": "stream.dispatch"}[sp.name]
+        assert by_id.get(sp.parent, sp).t0 <= sp.t0 <= sp.t1 \
+            <= by_id.get(sp.parent, sp).t1
+    steps = [sp for sp in spans if sp.name == "stream.step"]
+    # the operands come first, then the call, inside the dispatch
+    ops, call = (next(sp for sp in spans if sp.name == n
+                      and by_id[by_id[sp.parent].parent] is steps[1])
+                 for n in ("stream.operands", "stream.call"))
+    assert ops.t1 <= call.t0
+
+
 # --- single-device executor with instrumentation on -----------------------
 
 def _stream_executor():
@@ -420,3 +555,94 @@ def test_instrumented_arc(tmp_path):
     recs = EventLog.load(str(log_path))
     assert len(recs) > 10
     EventLog.validate(recs)
+
+
+# --- the fleet tick's span tree (subprocess: 4 forced devices) ------------
+
+_FLEET_SPANS_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import numpy as np
+    import jax, jax.numpy as jnp
+
+    from repro.core import pipeline as pipe
+    from repro.core import rules
+    from repro.obs import SLO, Tracer
+    from repro.stream import StreamConfig
+    from repro.stream.fleet import (FleetConfig, FleetController,
+                                    FleetExecutor)
+
+    D, BATCH, E, R = 3, 32, 4, 2
+    edge_fn = lambda p, b: (b * 1.5, b[:, :5])
+    core_fn = lambda p, b: (b + 100.0, b[:, :5])
+    engine = rules.RuleEngine([
+        rules.threshold_rule("hot", 0, ">=", 1.0, rules.C_SEND_CORE)])
+    scfg = StreamConfig(micro_batch=BATCH, window=16, stride=16,
+                        capacity=4 * BATCH)
+
+    def build(tracer):
+        ex = FleetExecutor(
+            FleetConfig(stream=scfg, num_shards=E, num_core=1,
+                        num_regions=R, core_budget=8),
+            engine, pipe.two_tier_pipeline(edge_fn, core_fn, engine))
+        kw = {} if tracer is None else {"tracer": tracer}
+        ctl = FleetController(ex, slos=(SLO("e2e", target_seconds=1.0),),
+                              **kw)
+        if tracer is not None:
+            ex.set_tracer(tracer)
+        return ex, ctl, ex.init_state(D)
+
+    tr = Tracer()
+    runs = {"on": build(tr), "off": build(None)}
+    rng = np.random.default_rng(0)
+    TICKS = 3
+    for t in range(TICKS):
+        items = jnp.asarray(rng.standard_normal((E, BATCH, D)), jnp.float32)
+        ts = jnp.asarray(np.tile(t * BATCH + np.arange(BATCH), (E, 1)),
+                         jnp.float32)
+        for key, (ex, ctl, state) in runs.items():
+            state = ex.step(state, items, ts)[0]
+            ctl.tick(state)
+            runs[key] = (ex, ctl, state)
+    for ex, ctl, _ in runs.values():
+        assert ex.trace_count == ctl.max_trace_count == 1, \\
+            (ex.trace_count, ctl.max_trace_count)
+
+    spans = tr.spans
+    by_id = {sp.id: sp for sp in spans}
+    want = {"fleet.step": None, "fleet.dispatch": "fleet.step",
+            "fleet.operands": "fleet.dispatch",
+            "fleet.call": "fleet.dispatch",
+            "fleet.device_execute": "fleet.step",
+            "control.tick": None, "control.pull": "control.tick",
+            "control.decide": "control.tick"}
+    names = [sp.name for sp in spans]
+    assert sorted(set(names)) == sorted(want), sorted(set(names))
+    for name in want:
+        assert names.count(name) == TICKS, (name, names.count(name))
+    for sp in spans:
+        got = by_id[sp.parent].name if sp.parent is not None else None
+        assert got == want[sp.name], (sp.name, got)
+        if sp.parent is not None:
+            up = by_id[sp.parent]
+            assert up.t0 <= sp.t0 <= sp.t1 <= up.t1, sp.name
+    print("SPANS_OK")
+""")
+
+
+def test_fleet_span_tree(tmp_path):
+    """With a tracer on, every fleet tick is the tree fleet.step >
+    fleet.dispatch > (fleet.operands, fleet.call), fleet.device_execute,
+    and every control tick, SLO lane included, control.tick >
+    control.pull, control.decide; the trace counts match those of an
+    untraced twin."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath(
+        os.path.join(os.path.dirname(__file__), "..", "src"))
+    script = tmp_path / "fleet_spans.py"
+    script.write_text(_FLEET_SPANS_SCRIPT)
+    out = subprocess.run([sys.executable, str(script)], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "SPANS_OK" in out.stdout
