@@ -3,10 +3,11 @@
 The idempotent-ingestion dedupe window as fixed-shape masked ops,
 designed to fuse into the executor's single traced step: event-id
 hashing (FNV-1a over the raw f32 bit patterns of the wire row), a
-bounded seen-window membership test (``[N, K]`` compare — the window
-is a traced ``uint32[K]`` ring operand, so sizing it is a config
-change, consulting it is not a recompile), and the accepted-hash
-recording scatter.  Semantics are pinned bit-for-bit against the
+bounded seen-window membership test (one sort of the ``K`` ring
+entries and the ``N`` offered ids, O((N+K) log(N+K)) — the window is a
+traced ``uint32[K]`` ring operand, so sizing it is a config change,
+consulting it is not a recompile), and the accepted-hash recording
+scatter.  Semantics are pinned bit-for-bit against the
 pure-numpy oracle in ``ref.py`` (``tests/test_ingest.py``).
 
 These are deliberately *not* jit-wrapped: they run inside the
@@ -45,15 +46,33 @@ def dedupe_window(hashes: jnp.ndarray, offered: jnp.ndarray,
     wins, FIFO); ``fresh = offered & ~dup``.  A ``seen`` ring of size
     0 disables the window (everything offered is fresh) — the caller
     skips the stage statically in that case, this is just the
-    consistent limit."""
+    consistent limit.
+
+    One sort answers both questions in O((N+K) log(N+K)), with no
+    ``[N, N]`` or ``[N, K]`` intermediate: the ring (positions
+    ``0..K-1``) and the batch (``K..K+N-1``) are sorted together by
+    ``(hash, position)``, with non-offered rows pushed past every
+    valid position.  Equal hashes then sit in a run, valid entries
+    first and in offer order, so a valid batch row is a duplicate
+    exactly when the entry just before it holds the same hash (that
+    entry is then valid too: a ring entry or an earlier offered row).
+    A second sort, keyed by position, puts the flags back in offer
+    order."""
     offered = jnp.asarray(offered, bool)
-    if seen.shape[0] == 0:
+    k = seen.shape[0]
+    if k == 0:
         return offered, jnp.zeros(offered.shape, bool)
-    in_seen = jnp.any(hashes[:, None] == seen[None, :], axis=1)
-    n = hashes.shape[0]
-    earlier = (hashes[:, None] == hashes[None, :]) & offered[None, :]
-    earlier &= jnp.arange(n)[None, :] < jnp.arange(n)[:, None]
-    dup = offered & (in_seen | jnp.any(earlier, axis=1))
+    m = k + hashes.shape[0]
+    key = jnp.concatenate([seen, hashes])
+    idx = jnp.arange(m, dtype=jnp.int32)
+    valid = jnp.concatenate([jnp.ones((k,), bool), offered])
+    pos = jnp.where(valid, idx, idx + m)
+    key, pos = jax.lax.sort((key, pos), num_keys=2, is_stable=False)
+    dup = jnp.concatenate([
+        jnp.zeros((1,), bool),
+        (pos[1:] < m) & (key[1:] == key[:-1])])
+    _, dup = jax.lax.sort((pos % m, dup), is_stable=False)
+    dup = dup[k:]
     return offered & ~dup, dup
 
 

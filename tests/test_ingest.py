@@ -24,6 +24,7 @@ import sys
 import textwrap
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -101,6 +102,89 @@ def test_dedupe_kernel_matches_ref(rng, n, k):
         seen_r, pos_r = seen_record_ref(seen_r, pos_r, h_r, accepted)
         np.testing.assert_array_equal(np.asarray(seen_o), seen_r)
         assert int(pos_o) == int(pos_r)
+
+
+def _case_collision_heavy(rng):
+    """Rows drawn from 4 distinct rows: nearly every hash repeats, some
+    already sit in the ring."""
+    base = rng.standard_normal((4, 4)).astype(np.float32)
+    h = row_hash_ref(base[rng.integers(0, 4, 256)])
+    seen = np.full((32,), np.uint32(EMPTY_HASH), np.uint32)
+    seen[:2] = row_hash_ref(base[:1])[0], h[7]
+    return h, rng.random(256) < 0.7, seen
+
+
+def _case_unoffered_first(rng):
+    """A non-offered slot holding the same id as a later offered one must
+    not make that later row a duplicate."""
+    rows = rng.standard_normal((6, 4)).astype(np.float32)
+    rows[4] = rows[1]
+    rows[5] = rows[3]
+    offered = np.array([1, 0, 1, 0, 1, 1], bool)
+    seen = np.full((2,), np.uint32(EMPTY_HASH), np.uint32)
+    return row_hash_ref(rows), offered, seen
+
+
+def _case_seen_and_repeated(rng):
+    """An id in the ring that the batch also repeats: every offered copy
+    is a duplicate, the first included."""
+    rows = rng.standard_normal((12, 4)).astype(np.float32)
+    rows[[2, 5, 9]] = rows[0]
+    h = row_hash_ref(rows)
+    seen = np.full((8,), np.uint32(EMPTY_HASH), np.uint32)
+    seen[3] = h[0]
+    return h, np.ones(12, bool), seen
+
+
+def _case_empty_slots(rng):
+    """A ring mostly of ``EMPTY_HASH`` slots, several of them, among real
+    ids: empty slots match no row (row ids are never ``EMPTY_HASH``)."""
+    rows = rng.standard_normal((20, 4)).astype(np.float32)
+    h = row_hash_ref(rows)
+    seen = np.full((16,), np.uint32(EMPTY_HASH), np.uint32)
+    seen[[1, 6, 11]] = h[[3, 8, 15]]
+    return h, rng.random(20) < 0.9, seen
+
+
+def _case_deployment_ring(rng):
+    """N = 4,096 against a full K = 1,024 ring, with ring hits and
+    in-batch repeats."""
+    rows = rng.standard_normal((4096, 4)).astype(np.float32)
+    rows[rng.integers(0, 4096, 300)] = rows[rng.integers(0, 4096, 300)]
+    h = row_hash_ref(rows)
+    seen = row_hash_ref(rng.standard_normal((1024, 4)).astype(np.float32))
+    seen[rng.permutation(1024)[:100]] = h[rng.integers(0, 4096, 100)]
+    return h, rng.random(4096) < 0.95, seen
+
+
+@pytest.mark.parametrize("case", [
+    _case_collision_heavy, _case_unoffered_first, _case_seen_and_repeated,
+    _case_empty_slots, _case_deployment_ring],
+    ids=lambda c: c.__name__.removeprefix("_case_"))
+def test_dedupe_sorted_membership_matches_ref(rng, case):
+    """The sort-based membership test against the oracle on the inputs
+    its ordering argument depends on."""
+    h, offered, seen = case(rng)
+    fresh_o, dup_o = dedupe_window(jnp.asarray(h), jnp.asarray(offered),
+                                   jnp.asarray(seen))
+    fresh_r, dup_r = dedupe_window_ref(h, offered, seen)
+    np.testing.assert_array_equal(np.asarray(fresh_o), fresh_r)
+    np.testing.assert_array_equal(np.asarray(dup_o), dup_r)
+    if case is _case_unoffered_first:
+        np.testing.assert_array_equal(fresh_r, offered)
+    if case is _case_seen_and_repeated:
+        assert dup_r[[0, 2, 5, 9]].all()
+
+
+def test_dedupe_has_no_quadratic_intermediate():
+    """The membership test lowers without an ``[N, N]`` or ``[N, K]``
+    tensor at N = 4,096, K = 1,024: the quadratic compare stays gone."""
+    text = jax.jit(dedupe_window).lower(
+        jnp.zeros((4096,), jnp.uint32), jnp.ones((4096,), bool),
+        jnp.zeros((1024,), jnp.uint32)).as_text()
+    assert "sort" in text
+    for shape in ("4096x4096", "4096x1024", "1024x4096"):
+        assert shape not in text
 
 
 def test_row_hash_ignores_nothing(rng):
