@@ -27,10 +27,7 @@ import dataclasses
 
 import numpy as np
 
-
-def seed_sequence(seed: int, *words: int) -> np.random.SeedSequence:
-    """SeedSequence of a run seed (any integer) and extra words."""
-    return np.random.SeedSequence([seed & (2 ** 64 - 1), seed < 0, *words])
+from bench.deployment import seed_sequence
 
 
 @dataclasses.dataclass

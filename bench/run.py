@@ -4,11 +4,14 @@
 
 A cell (``BENCHMARK.json``, ``workloads``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``).  One process: set-up (host pool of
-micro-batches from the seed, the program built from the configuration,
-two warm-up ticks that compile or load the step), then a window of
-``--seconds`` through the program's normal entry points, then the plain
-reference over every tick and the comparison that decides ``correct``.
+(``bench/traffic/<mix>.json``); the configuration names its deployment,
+the module that brings the generator, the program's build, the plain
+reference and the comparison (``bench/deployment.py``).  One process:
+set-up (the generator's host pool from the seed, the program built from
+the configuration, two warm-up ticks that compile or load the step),
+then a window of ``--seconds`` through the program's normal entry
+points, then the plain reference over every tick and the comparison
+that decides ``correct``.
 
 With ``--trace 0`` the last line of standard output carries the cell's
 end-to-end metrics; with ``--trace 1`` a JAX profiler trace of the last
@@ -46,9 +49,9 @@ for _p in (str(ROOT / "src"), str(ROOT)):
 #: seconds at the end of the window a ``--trace 1`` run records in the
 #: profiler
 TRACE_SECONDS = 3.0
-#: share of the window's ticks whose full outputs (channel means and
-#: pipeline outputs) are compared; counts, features, codes and the
-#: escalated mask are compared on every tick
+#: share of the window's ticks, drawn from the seed, that the comparison
+#: sees in full (the deployment's ``trim`` with ``full``); the others it
+#: sees as ``trim`` keeps them
 FULL_SHARE = 1 / 16
 #: warm-up ticks, run through the same step before the window
 WARMUP_TICKS = 2
@@ -130,14 +133,6 @@ def wait_until(t: float) -> None:
             time.sleep(left - 0.001)
 
 
-def trim(out: dict, full: bool) -> dict:
-    """What the comparison keeps of a tick's host outputs."""
-    keep = ("window_count", "consequence", "escalated", "features")
-    if full:
-        keep += ("aggregates", "outputs")
-    return {k: out[k].copy() for k in keep}
-
-
 def drive(sysm, item, producer, first: int, rows: int, open_loop: bool,
           rate: float, seconds: float, span, on_tick=None, phases=None):
     """The measured window: ticks from ``item`` on, through the system,
@@ -208,29 +203,31 @@ def _run_cell(bench, wl, cfg, traffic, seed, seconds, trace, lane, log,
     import jax
     import numpy as np
 
-    from bench import compare, reference, system as systems
+    from bench import deployment
     from bench import trace_reduce as TR
-    from bench.generator import Generator, seed_sequence
     from repro.obs.trace import Tracer
 
-    b, shards = cfg["micro_batch"], cfg["shards"]
+    dep = deployment.load(cfg)
+    b, shards = dep.rows_per_tick(cfg), cfg["shards"]
     marks = [("imports and devices", time.perf_counter())]
-    gen = Generator(cfg, traffic, seed)
+    gen = dep.Generator(cfg, traffic, seed)
     marks.append(("host pool", time.perf_counter()))
     tracer = Tracer() if trace else None
-    sysm = systems.build(cfg, tracer, lane)
+    sysm = dep.build(cfg, tracer, lane)
     marks.append(("program built", time.perf_counter()))
     nullspan = contextlib.nullcontext()
 
     def span(name):
         return jax.profiler.TraceAnnotation(name) if trace else nullspan
 
-    keep_full = np.random.default_rng(seed_sequence(seed, 2)).random(
-        1 << 20) < FULL_SHARE
-    outs: dict[int, list[dict]] = {}
+    keep_full = np.random.default_rng(
+        deployment.seed_sequence(seed, 2)).random(1 << 20) < FULL_SHARE
+    # tick -> (compared in full, each shard's trimmed outputs)
+    outs: dict[int, tuple[bool, list[dict]]] = {}
     for t in range(WARMUP_TICKS):
         items, ts = gen.batch(t)
-        outs[t] = [trim(o, True) for o in sysm.step(items, ts, span)]
+        outs[t] = (True, [dep.trim(o, True)
+                          for o in sysm.step(items, ts, span)])
         marks.append((f"warm-up tick {t}", time.perf_counter()))
     compiled_before = sysm.compiled()
     log("set-up: " + ", ".join(
@@ -254,7 +251,8 @@ def _run_cell(bench, wl, cfg, traffic, seed, seconds, trace, lane, log,
     profiled = {"from": None, "at": None}
 
     def on_tick(t, out, n, elapsed):
-        outs[t] = [trim(o, bool(keep_full[t])) for o in out]
+        full = bool(keep_full[t])
+        outs[t] = (full, [dep.trim(o, full) for o in out])
         if trace and profiled["from"] is None \
                 and elapsed >= seconds - TRACE_SECONDS:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -302,26 +300,23 @@ def _run_cell(bench, wl, cfg, traffic, seed, seconds, trace, lane, log,
 
     # -- the plain reference over every tick, then the comparison ------
     t_ref = time.perf_counter()
-    cmp = compare.Comparison()
-    last_events = {}
-    with reference.Reference(cfg, traffic, seed, systems.core_params(cfg),
-                             pool=gen.pool if shards == 1 else None) as ref:
+    cmp = dep.Comparison()
+    newest = {}
+    with dep.Reference(cfg, traffic, seed, gen) as ref:
         for t in sorted(outs):
-            full = "aggregates" in outs[t][0]
+            full, prog = outs[t]
             r = ref.tick(t, full)
-            cmp.tick(outs[t], r, full)
+            cmp.tick(prog, r, full)
             if t >= first:
-                last_events[t] = (r[0]["last_event"],
-                                  r[0]["count"] >= cfg["min_count"])
+                newest[t] = dep.newest_events(r, cfg)
         ref_counters = ref.counters()
     off = cmp.counters(prog_counters, ref_counters)
     if info is not None:
-        info.update(program=prog_counters, reference=ref_counters,
-                    collisions=ref.collisions)
+        info.update(program=prog_counters, reference=ref_counters)
     ref_s = time.perf_counter() - t_ref
-    log(f"reference: {len(outs)} ticks x {shards} shards in {ref_s:.1f} s; "
-        f"32-bit id collisions seen (re-delivery verdicts on rows that "
-        f"differ): {ref.collisions}", file=sys.stderr)
+    ref_log = getattr(ref, "log", "")
+    log(f"reference: {len(outs)} ticks x {shards} shards in {ref_s:.1f} s"
+        + (f"; {ref_log}" if ref_log else ""), file=sys.stderr)
     if off:
         log(f"counters that differ from the reference: {off}",
             file=sys.stderr)
@@ -350,7 +345,7 @@ def _run_cell(bench, wl, cfg, traffic, seed, seconds, trace, lane, log,
               "events_per_s": sum(e <= end for *_, e in ticks) * shards * b
               / seconds}
     if open_loop and ticks:
-        lat = emission_latencies(ticks, last_events, start, first, b, rate)
+        lat = emission_latencies(ticks, newest, start, first, b, rate)
         for m in metrics_of(bench["end_to_end"], wl["name"]):
             q = re.fullmatch(r"emit_p(\d+)_ms", m["name"])
             if q:
@@ -369,7 +364,7 @@ def _run_cell(bench, wl, cfg, traffic, seed, seconds, trace, lane, log,
                  if start <= sp[1] and sp[2] <= profiled["at"]]
         metrics, breakdown = traced_metrics(
             bench, wl, cfg, traffic, trace_dir, scopes, ticks[:traced],
-            spans, device, log)
+            spans, device, log, dep.work(cfg))
         result["metrics"], result["breakdown"] = metrics, breakdown
     checks = cmp.checks()
     result["checks"] = checks
@@ -437,34 +432,38 @@ def slow_ticks(ticks, phases, names, made_s, use0, use1) -> str:
             f"{use1.ru_stime - use0.ru_stime:.2f} s system")
 
 
-def emission_latencies(ticks, last_events, start: float, first: int,
+def emission_latencies(ticks, newest, start: float, first: int,
                        rows: int, rate: float):
-    """Per emitted window of every tick: its emission (the tick's
-    outputs on the host) less the creation of its newest event."""
+    """Per emitted result of every tick: its emission (the tick's
+    outputs on the host) less the creation of its newest event;
+    ``newest[t]`` as the deployment's ``newest_events`` gives it."""
     import numpy as np
 
     lat = []
     for t, _, _, emitted_at in ticks:
-        event, emitted = last_events[t]
-        created = start + (event[emitted] - first * rows) / rate
+        event = newest[t]
+        created = start + (event[event >= 0] - first * rows) / rate
         lat.append(emitted_at - created)
     return np.concatenate(lat)
 
 
 def traced_metrics(bench, wl, cfg, traffic, trace_dir, scopes, ticks,
-                   spans, device, log):
+                   spans, device, log, work):
     """The cell's per-layer metrics and the breakdown, from the
     profiler's trace, the unprofiled ticks and the program's spans;
-    adds ``busy_s`` and ``window_s`` to ``device``."""
+    ``work`` is the deployment's counts for the roofline readers.  Adds
+    ``busy_s`` and ``window_s`` to ``device``."""
     import numpy as np
 
     from bench import trace_reduce as TR
-    from bench import work
 
     path = next(trace_dir.glob("**/*.xplane.pb"))
-    names = {"bench.tick", "bench.h2d", "bench.d2h", "stream.dispatch",
-             "fleet.dispatch", "fleet.device_execute", "control.tick",
-             "stream_step", "fleet_tick"}
+    # host spans that label the device's idle gaps in the breakdown
+    names = {"bench.tick", "bench.h2d", "bench.d2h", "stream.step",
+             "stream.dispatch", "stream.operands", "stream.call",
+             "fleet.step", "fleet.dispatch", "fleet.operands", "fleet.call",
+             "fleet.device_execute", "control.tick", "control.pull",
+             "control.decide", "stream_step", "fleet_tick"}
     tr = TR.load(path, names.__contains__, scopes)
     shutil.rmtree(trace_dir, ignore_errors=True)
     tick_spans = sorted((a, e) for n, a, e in tr.host if n == "bench.tick")
@@ -475,7 +474,7 @@ def traced_metrics(bench, wl, cfg, traffic, trace_dir, scopes, ticks,
                            f"in bench/peaks.json")
     ctx = Ctx(cfg=cfg, traffic=traffic, ticks=ticks, spans=spans, trace=tr,
               window=(lo, hi), traced_ticks=len(tick_spans),
-              peak=peaks[device["kind"]], work=work.window_rules(cfg),
+              peak=peaks[device["kind"]], work=work,
               log=lambda *a: log(*a, file=sys.stderr))
     metrics = {}
     for m in metrics_of(bench["per_layer"], wl["name"]):

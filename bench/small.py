@@ -1,12 +1,9 @@
-"""A cell cut to a size a CPU test can hold: 2,048-row ticks, windows
-of 16 every 8 rows, a dedupe window of 64, the fleet's budgets scaled
-with them, re-deliveries 0.2 to 4 ms back (some inside the dedupe
-window, some past it), and the fused tick on its jnp lane (the Pallas
-kernel runs only on the chip).  Every mechanism of the full cell stays on: re-sent
-rows, contract rejects, NaN rows, late and out-of-order rows, binding
-fog and core budgets."""
+"""A cell cut to a size a CPU test can hold, by its deployment's
+``small`` (``bench/deployment.py``), with the sensor deployment's fused
+tick on its jnp lane (the Pallas kernel runs only on the chip)."""
 from __future__ import annotations
 
+from bench import deployment
 from bench.run import cell_spec
 
 LANE = {"backend": "jnp", "fused": True}
@@ -14,20 +11,7 @@ LANE = {"backend": "jnp", "fused": True}
 
 def small_cell(name: str) -> tuple[dict, dict, dict, dict]:
     bench, wl, cfg, traffic = cell_spec(name)
-    cfg = dict(cfg, micro_batch=2048, ring_capacity=2048, window=16,
-               stride=8, dedupe_window=64)
-    traffic = dict(traffic, resent_batches_per_tick=2, resent_batch_rows=8,
-                   resent_delay_s=[2e-4, 4e-3],
-                   out_of_contract_per_tick=8, nan_per_tick=2,
-                   out_of_order_per_tick=8, late_per_tick=4, pool_batches=2,
-                   rate=2048 / 0.05)
-    if cfg.get("fleet"):
-        fl = cfg["fleet"]
-        cfg["fleet"] = dict(
-            fl, core_budget=16, core_budget_max=32, fog_budget=16,
-            fog_budget_max=32,
-            core_policy=dict(fl["core_policy"], min=4, max=32),
-            fog_policy=dict(fl["fog_policy"], min=4, max=32))
+    cfg, traffic = deployment.load(cfg).small(cfg, traffic)
     return bench, wl, cfg, traffic
 
 

@@ -39,17 +39,17 @@ def main() -> int:
     import jax
     import numpy as np
 
-    from bench import run, system
-    from bench.generator import Generator
+    from bench import deployment, run
 
     jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     bench, wl, cfg, traffic = run.cell_spec(args.workload)
     jax.config.update("jax_default_matmul_precision",
                       cfg["precision"]["matmul"])
-    b = cfg["micro_batch"]
-    gen = Generator(cfg, traffic, args.seed)
-    sysm = system.build(cfg)
+    dep = deployment.load(cfg)
+    b = dep.rows_per_tick(cfg)
+    gen = dep.Generator(cfg, traffic, args.seed)
+    sysm = dep.build(cfg, None, None)
     span = lambda name: contextlib.nullcontext()        # noqa: E731
     for t in range(run.WARMUP_TICKS):
         sysm.step(*gen.batch(t), span)
