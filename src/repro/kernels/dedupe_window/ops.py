@@ -24,12 +24,14 @@ from repro.kernels.dedupe_window.ref import (EMPTY_HASH, FNV_BASIS,
 
 
 def row_hash(rows: jnp.ndarray) -> jnp.ndarray:
-    """[N, C] f32 wire rows -> [N] uint32 FNV-1a event ids (exact — the
-    f32 words are bitcast, not rounded, so a re-sent row hashes
-    identically on every backend).  Hash 0 is reserved for "empty
-    seen slot" and real rows landing on it are bumped to 1."""
-    words = jax.lax.bitcast_convert_type(
-        jnp.asarray(rows, jnp.float32), jnp.uint32)
+    """[N, C] f32 (or int32) wire rows -> [N] uint32 FNV-1a event ids
+    (exact — the 32-bit words are bitcast, not rounded, so a re-sent
+    row hashes identically on every backend).  Hash 0 is reserved for
+    "empty seen slot" and real rows landing on it are bumped to 1."""
+    rows = jnp.asarray(rows)
+    if rows.dtype != jnp.int32:
+        rows = rows.astype(jnp.float32)
+    words = jax.lax.bitcast_convert_type(rows, jnp.uint32)
     h = jnp.full(words.shape[:1], FNV_BASIS, jnp.uint32)
     for c in range(words.shape[1]):        # C is static (trace constant)
         h = (h ^ words[:, c]) * FNV_PRIME

@@ -24,15 +24,17 @@ EMPTY_HASH = np.uint32(0)
 
 
 def row_hash_ref(rows: np.ndarray) -> np.ndarray:
-    """[N, C] f32 wire rows -> [N] uint32 FNV-1a event ids.
+    """[N, C] f32 (or int32) wire rows -> [N] uint32 FNV-1a event ids.
 
     Hashes the raw bit patterns (f32 reinterpreted as u32), so the id
     is exact under retransmission: a re-sent row hashes identically, a
     row differing in any bit does not (up to 32-bit collisions; the
     window is a dedupe heuristic, not a cryptographic ledger).
     """
-    words = np.ascontiguousarray(
-        np.asarray(rows, np.float32)).view(np.uint32)
+    rows = np.asarray(rows)
+    if rows.dtype != np.int32:
+        rows = rows.astype(np.float32)
+    words = np.ascontiguousarray(rows).view(np.uint32)
     h = np.full(words.shape[0], FNV_BASIS, np.uint32)
     with np.errstate(over="ignore"):
         for c in range(words.shape[1]):

@@ -44,6 +44,7 @@ from repro.obs import costmodel as OC
 from repro.obs import latency as OL
 from repro.obs.trace import NULL_TRACER
 from repro.stream import ingest as I
+from repro.stream import keyed as K
 from repro.stream import windows as W
 
 
@@ -63,8 +64,13 @@ class StreamConfig:
     overlap_ingest: bool = False   # stage tick N+1 during tick N (run())
     ingest_int8: bool = False      # int8-quantize staged telemetry (lossy)
     admission: I.AdmissionPlan = I.AdmissionPlan()   # dedupe + contract lane
+    keyed: K.KeyedPlan | None = None   # keyed windows in place of windows,
+    #                                    rules and pipeline
 
     def __post_init__(self):
+        if self.keyed is not None and self.fused:
+            raise ValueError("a keyed plan replaces the fused window "
+                             "kernel: set fused=False")
         if not (0 < self.stride <= self.window):
             raise ValueError(f"need 0 < stride <= window, got {self}")
         if self.micro_batch % self.stride or self.micro_batch < self.stride:
@@ -75,6 +81,12 @@ class StreamConfig:
         if self.ingest_int8 and not self.overlap_ingest:
             raise ValueError("ingest_int8 rides the overlapped ingest "
                              "stager: set overlap_ingest=True too")
+
+    @property
+    def row_dtype(self) -> str:
+        """The ring's words: the windows read float32 channels, a keyed
+        plan exact int32 ids and times."""
+        return "float32" if self.keyed is None else "int32"
 
     @property
     def windows_per_step(self) -> int:
@@ -103,30 +115,42 @@ class StreamMetrics(NamedTuple):
     windows_dropped: jnp.ndarray   # quality-dropped
     core_overflow: jnp.ndarray     # flagged beyond core_capacity
     drift_counts: jnp.ndarray      # [D] per-field contract violations
+    keyed: K.KeyedMetrics | None = None   # a keyed plan's counters
 
     def as_dict(self) -> dict[str, int | list[int]]:
         """Host-side snapshot: one ``jax.device_get`` for the whole
         tuple (a single transfer, not one sync per counter), plain
         ints.  Array counters (per-shard [E] views, the per-field
-        ``drift_counts``) come back as lists of ints."""
+        ``drift_counts``) come back as lists of ints; a keyed plan's
+        counters follow under their own names (none without one)."""
         host = jax.device_get(self)
+        pairs = list(zip(self._fields[:-1], host[:-1]))
+        if host.keyed is not None:
+            pairs += zip(K.KeyedMetrics._fields, host.keyed)
         return {k: v.tolist() if getattr(v, "ndim", 0) else int(v)
-                for k, v in zip(self._fields, host)}
+                for k, v in pairs}
 
 
-def _zero_metrics(feature_dim: int) -> StreamMetrics:
+#: the counters every executor keeps (``StreamMetrics`` less ``keyed``)
+COUNTERS = StreamMetrics._fields[:-1]
+
+
+def _zero_metrics(feature_dim: int, keyed: bool = False) -> StreamMetrics:
     # distinct buffers per counter: the step donates its state, and XLA
     # rejects donating one aliased buffer through several arguments
     return StreamMetrics(*(jnp.zeros((), jnp.int32)
-                           for _ in StreamMetrics._fields[:-1]),
-                         drift_counts=jnp.zeros((feature_dim,), jnp.int32))
+                           for _ in COUNTERS[:-1]),
+                         drift_counts=jnp.zeros((feature_dim,), jnp.int32),
+                         keyed=K.keyed_metrics_init() if keyed else None)
 
 
 #: Ring rows are [ts | ingest_wall | features]: ``META_COLS`` leading
 #: metadata columns before the D feature columns.  Column 0 is the
 #: event timestamp; column 1 the *ingest wall time* (seconds since the
 #: executor's epoch, f32) stamped at enqueue — the birth stamp the
-#: event-time latency lineage measures every stage against.
+#: event-time latency lineage measures every stage against.  With a
+#: keyed plan (``row_dtype`` int32) every column is an int32 word: the
+#: event time as an integer, the stamp's float32 bits.
 META_COLS = 2
 
 
@@ -137,6 +161,7 @@ class StreamState(NamedTuple):
     max_ts: jnp.ndarray            # [] f32 running max event time
     metrics: StreamMetrics
     adm: I.AdmissionState          # dedupe-window ring ([0] when inert)
+    keyed: K.KeyedState | None = None   # a keyed plan's table
 
 
 class StepOutput(NamedTuple):
@@ -176,6 +201,131 @@ class IngestResult(NamedTuple):
     q_lat: jnp.ndarray             # [B] f32 queueing delay per dequeued row
     q_mask: jnp.ndarray            # [B] bool which rows were dequeued
     w_birth: jnp.ndarray           # [NW] f32 oldest ingest stamp per window
+
+
+class IngestFront(NamedTuple):
+    """Front half of a stream step (ingest -> admission -> ring ->
+    watermark), shared by the windowed step (``ingest_and_window``) and
+    the keyed one."""
+    rb: rbuf.RingBuffer
+    rows: jnp.ndarray              # [B, META_COLS + D] dequeued rows
+    valid: jnp.ndarray             # [B] bool dequeued and not late
+    dequeued: jnp.ndarray          # [B] bool
+    max_ts: jnp.ndarray
+    n_in: jnp.ndarray
+    n_accepted: jnp.ndarray
+    n_late: jnp.ndarray
+    n_late_excluded: jnp.ndarray
+    n_replayed: jnp.ndarray
+    n_deduped: jnp.ndarray
+    n_backfilled: jnp.ndarray
+    drift: jnp.ndarray
+    adm: I.AdmissionState
+
+
+def ingest_front(cfg: StreamConfig, state: StreamState, items: jnp.ndarray,
+                 ts: jnp.ndarray, watermark_ts: jnp.ndarray | None = None,
+                 offer_mask: jnp.ndarray | None = None,
+                 excluded_ref: jnp.ndarray | None = None,
+                 replay: jnp.ndarray | None = None,
+                 mode: jnp.ndarray | None = None,
+                 now: jnp.ndarray | float = 0.0) -> IngestFront:
+    """stamp -> admission -> enqueue -> dequeue -> watermark; the
+    arguments are ``ingest_and_window``'s.  Rows carry
+    ``cfg.row_dtype`` words; the watermark compares event times as
+    float32 (exact for integer times below 2**24)."""
+    now = jnp.asarray(now, jnp.float32)
+    if replay is not None and mode is not None:
+        raise ValueError("pass either replay= (bool shorthand) or "
+                         "mode= (stream.ingest mode code), not both")
+    if replay is not None:
+        mode = jnp.where(jnp.asarray(replay, bool),
+                         jnp.int32(I.MODE_REPLAY), jnp.int32(I.MODE_LIVE))
+    n_in = items.shape[0]
+    plan = cfg.admission
+    held = state.rb.head - state.rb.tail       # rows queued before this offer
+    with jax.named_scope("obs:ingest"):
+        dt = cfg.row_dtype
+        # int32 rows keep exact integer words and the stamp's float32 bits
+        stamp = now if dt == "float32" \
+            else jax.lax.bitcast_convert_type(now, jnp.int32)
+        rows_in = jnp.concatenate(
+            [ts.astype(dt)[:, None],
+             jnp.broadcast_to(stamp, (n_in, 1)),
+             items.astype(dt)],
+            axis=1)
+        if offer_mask is None:
+            n_offered = jnp.int32(n_in)
+        else:
+            n_offered = jnp.sum(offer_mask.astype(jnp.int32))
+        if plan.inert:
+            # statically no admission lane: the pre-existing enqueue
+            # path verbatim (bit-for-bit, zero added ops)
+            n_dedup = jnp.zeros((), jnp.int32)
+            drift = jnp.zeros((items.shape[1],), jnp.int32)
+            adm = state.adm
+            if offer_mask is None:
+                rb, n_acc = rbuf.enqueue(state.rb, rows_in)
+            else:
+                rb, n_acc = rbuf.enqueue(state.rb, rows_in, offer_mask)
+        else:
+            with jax.named_scope("obs:admission"):
+                gate = I.admission_gate(plan, state.adm, ts, items,
+                                        offer_mask, cfg.row_dtype)
+                rb, n_acc = rbuf.enqueue(state.rb, rows_in, gate.admit)
+                adm = I.admission_record(plan, state.adm, gate, n_acc)
+            n_dedup = gate.n_deduped
+            drift = gate.drift
+        rb, rows, valid = rbuf.dequeue(rb, cfg.micro_batch)
+    wm = state.max_ts if watermark_ts is None else watermark_ts
+    dequeued = valid
+    # the watermark reads event time as float32, whatever the row words
+
+    def ev():
+        return rows[:, 0].astype(jnp.float32)
+    if mode is None:
+        exempt = None
+    else:
+        # FIFO positional split: rows the ring held before this offer
+        # dequeue first and keep exact normal semantics; only the rows
+        # a replay/backfill offer contributed are lateness-exempt
+        mode = jnp.asarray(mode, jnp.int32)
+        reproc = mode >= I.MODE_REPLAY
+        pos = jnp.arange(cfg.micro_batch, dtype=held.dtype)
+        exempt = reproc & (pos >= held)
+    with jax.named_scope("obs:watermark"):
+        valid, n_late, max_ts = W.apply_watermark(
+            ev(), valid, wm, cfg.lateness, exempt=exempt)
+    max_ts = jnp.maximum(state.max_ts, max_ts)
+    if mode is None:
+        exempt = jnp.zeros(dequeued.shape, bool)
+        n_rep = jnp.zeros((), jnp.int32)
+        n_bf = jnp.zeros((), jnp.int32)
+    else:
+        n_ex = jnp.sum((exempt & dequeued).astype(jnp.int32))
+        n_rep = jnp.where(mode == I.MODE_REPLAY, n_ex, 0)
+        n_bf = jnp.where(mode == I.MODE_BACKFILL, n_ex, 0)
+        # reprocessed rows never advance the local event-time clock: a
+        # foreign/historical stream must not perturb it, or the host's
+        # own still-queued batches would arrive "late" against it
+        own_max = jnp.max(jnp.where(
+            dequeued & ~exempt, ev(),
+            jnp.asarray(jnp.finfo(jnp.float32).min)))
+        max_ts = jnp.where(reproc,
+                           jnp.maximum(state.max_ts, own_max),  # own rows
+                           max_ts)                     # foreign clock apart
+    if excluded_ref is None:
+        n_lx = jnp.zeros((), jnp.int32)
+    else:
+        n_lx = jnp.sum((valid & ~exempt
+                        & (ev() < excluded_ref - cfg.lateness))
+                       .astype(jnp.int32))
+
+    return IngestFront(
+        rb=rb, rows=rows, valid=valid, dequeued=dequeued, max_ts=max_ts,
+        n_in=n_offered, n_accepted=n_acc, n_late=n_late,
+        n_late_excluded=n_lx, n_replayed=n_rep, n_deduped=n_dedup,
+        n_backfilled=n_bf, drift=drift, adm=adm)
 
 
 def ingest_and_window(cfg: StreamConfig, engine: R.RuleEngine,
@@ -253,84 +403,10 @@ def ingest_and_window(cfg: StreamConfig, engine: R.RuleEngine,
       (the window-residency and end-to-end measurements' reference;
       all-invalid windows report 0 and are masked by ``emit``).
     """
-    if replay is not None and mode is not None:
-        raise ValueError("pass either replay= (bool shorthand) or "
-                         "mode= (stream.ingest mode code), not both")
-    if replay is not None:
-        mode = jnp.where(jnp.asarray(replay, bool),
-                         jnp.int32(I.MODE_REPLAY), jnp.int32(I.MODE_LIVE))
-    n_in = items.shape[0]
-    plan = cfg.admission
-    held = state.rb.head - state.rb.tail       # rows queued before this offer
     now = jnp.asarray(now, jnp.float32)
-    with jax.named_scope("obs:ingest"):
-        rows_in = jnp.concatenate(
-            [ts.astype(jnp.float32)[:, None],
-             jnp.broadcast_to(now, (n_in, 1)),
-             items.astype(jnp.float32)],
-            axis=1)
-        if offer_mask is None:
-            n_offered = jnp.int32(n_in)
-        else:
-            n_offered = jnp.sum(offer_mask.astype(jnp.int32))
-        if plan.inert:
-            # statically no admission lane: the pre-existing enqueue
-            # path verbatim (bit-for-bit, zero added ops)
-            n_dedup = jnp.zeros((), jnp.int32)
-            drift = jnp.zeros((items.shape[1],), jnp.int32)
-            adm = state.adm
-            if offer_mask is None:
-                rb, n_acc = rbuf.enqueue(state.rb, rows_in)
-            else:
-                rb, n_acc = rbuf.enqueue(state.rb, rows_in, offer_mask)
-        else:
-            with jax.named_scope("obs:admission"):
-                gate = I.admission_gate(plan, state.adm, ts, items,
-                                        offer_mask)
-                rb, n_acc = rbuf.enqueue(state.rb, rows_in, gate.admit)
-                adm = I.admission_record(plan, state.adm, gate, n_acc)
-            n_dedup = gate.n_deduped
-            drift = gate.drift
-        rb, rows, valid = rbuf.dequeue(rb, cfg.micro_batch)
-    wm = state.max_ts if watermark_ts is None else watermark_ts
-    dequeued = valid
-    if mode is None:
-        exempt = None
-    else:
-        # FIFO positional split: rows the ring held before this offer
-        # dequeue first and keep exact normal semantics; only the rows
-        # a replay/backfill offer contributed are lateness-exempt
-        mode = jnp.asarray(mode, jnp.int32)
-        reproc = mode >= I.MODE_REPLAY
-        pos = jnp.arange(cfg.micro_batch, dtype=held.dtype)
-        exempt = reproc & (pos >= held)
-    with jax.named_scope("obs:watermark"):
-        valid, n_late, max_ts = W.apply_watermark(
-            rows[:, 0], valid, wm, cfg.lateness, exempt=exempt)
-    max_ts = jnp.maximum(state.max_ts, max_ts)
-    if mode is None:
-        exempt = jnp.zeros(dequeued.shape, bool)
-        n_rep = jnp.zeros((), jnp.int32)
-        n_bf = jnp.zeros((), jnp.int32)
-    else:
-        n_ex = jnp.sum((exempt & dequeued).astype(jnp.int32))
-        n_rep = jnp.where(mode == I.MODE_REPLAY, n_ex, 0)
-        n_bf = jnp.where(mode == I.MODE_BACKFILL, n_ex, 0)
-        # reprocessed rows never advance the local event-time clock: a
-        # foreign/historical stream must not perturb it, or the host's
-        # own still-queued batches would arrive "late" against it
-        own_max = jnp.max(jnp.where(
-            dequeued & ~exempt, rows[:, 0],
-            jnp.asarray(jnp.finfo(jnp.float32).min)))
-        max_ts = jnp.where(reproc,
-                           jnp.maximum(state.max_ts, own_max),  # own rows
-                           max_ts)                     # foreign clock apart
-    if excluded_ref is None:
-        n_lx = jnp.zeros((), jnp.int32)
-    else:
-        n_lx = jnp.sum((valid & ~exempt
-                        & (rows[:, 0] < excluded_ref - cfg.lateness))
-                       .astype(jnp.int32))
+    f = ingest_front(cfg, state, items, ts, watermark_ts, offer_mask,
+                     excluded_ref, replay, mode, now)
+    rows, valid = f.rows, f.valid
 
     # cross-batch continuity: prepend the carried W-S samples
     seq = jnp.concatenate([state.carry, rows], axis=0)
@@ -377,18 +453,35 @@ def ingest_and_window(cfg: StreamConfig, engine: R.RuleEngine,
             cons = jnp.where(emit, cons, R.C_NONE)
     record = jnp.concatenate([feats, agg], axis=1)         # [NW, 5 + D]
     return IngestResult(
-        rb=rb,
+        rb=f.rb,
         carry=seq[seq.shape[0] - cfg.carry_len:]
         if cfg.carry_len else seq[:0],
         carry_valid=seq_valid[seq_valid.shape[0] - cfg.carry_len:]
         if cfg.carry_len else seq_valid[:0],
-        max_ts=max_ts, aggregates=agg, window_count=wcount, features=feats,
-        consequence=cons, emit=emit, record=record,
-        n_in=n_offered, n_accepted=n_acc,
-        n_dequeued=jnp.sum(valid.astype(jnp.int32)) + n_late,
-        n_late=n_late, n_late_excluded=n_lx, n_replayed=n_rep,
-        n_deduped=n_dedup, n_backfilled=n_bf, drift=drift, adm=adm,
-        q_lat=q_lat, q_mask=dequeued, w_birth=w_birth)
+        max_ts=f.max_ts, aggregates=agg, window_count=wcount,
+        features=feats, consequence=cons, emit=emit, record=record,
+        n_in=f.n_in, n_accepted=f.n_accepted,
+        n_dequeued=jnp.sum(valid.astype(jnp.int32)) + f.n_late,
+        n_late=f.n_late, n_late_excluded=f.n_late_excluded,
+        n_replayed=f.n_replayed, n_deduped=f.n_deduped,
+        n_backfilled=f.n_backfilled, drift=f.drift, adm=f.adm,
+        q_lat=q_lat, q_mask=f.dequeued, w_birth=w_birth)
+
+
+def _admission_counters(m: StreamMetrics, f: IngestFront | IngestResult,
+                        n_dequeued: jnp.ndarray) -> dict:
+    """The step, admission and watermark counters after one tick."""
+    return dict(
+        steps=m.steps + jnp.int32(1),
+        items_offered=m.items_offered + f.n_in,
+        items_accepted=m.items_accepted + f.n_accepted,
+        items_rejected=m.items_rejected
+        + (f.n_in - f.n_accepted - f.n_deduped),
+        items_dequeued=m.items_dequeued + n_dequeued,
+        items_late=m.items_late + f.n_late,
+        items_replayed=m.items_replayed + f.n_replayed,
+        items_deduped=m.items_deduped + f.n_deduped,
+        items_backfilled=m.items_backfilled + f.n_backfilled)
 
 
 def advance_metrics(m: StreamMetrics, ing: IngestResult,
@@ -401,18 +494,8 @@ def advance_metrics(m: StreamMetrics, ing: IngestResult,
     (``items_rejected`` covers contract violations and ring
     backpressure; deduped re-deliveries are accounted apart — they are
     not an error, they are the admission lane doing its job)."""
-    one = jnp.int32(1)
     return StreamMetrics(
-        steps=m.steps + one,
-        items_offered=m.items_offered + ing.n_in,
-        items_accepted=m.items_accepted + ing.n_accepted,
-        items_rejected=m.items_rejected
-        + (ing.n_in - ing.n_accepted - ing.n_deduped),
-        items_dequeued=m.items_dequeued + ing.n_dequeued,
-        items_late=m.items_late + ing.n_late,
-        items_replayed=m.items_replayed + ing.n_replayed,
-        items_deduped=m.items_deduped + ing.n_deduped,
-        items_backfilled=m.items_backfilled + ing.n_backfilled,
+        **_admission_counters(m, ing, ing.n_dequeued),
         windows_emitted=m.windows_emitted
         + jnp.sum(ing.emit.astype(jnp.int32)),
         rules_fired=m.rules_fired
@@ -433,10 +516,17 @@ class StreamExecutor:
     (``window_feature_names()`` gives the column order).
     pipeline: run on the [NW, 5 + D] window records (features
     concatenated with the mean aggregate) — stage fns can slice either.
+
+    With a keyed plan (``cfg.keyed``) the step runs the keyed stage
+    (``stream.keyed``) in place of windows, rules and pipeline, and
+    takes no engine or pipeline.
     """
 
-    def __init__(self, cfg: StreamConfig, engine: R.RuleEngine,
-                 pipeline: DataDrivenPipeline):
+    def __init__(self, cfg: StreamConfig, engine: R.RuleEngine | None = None,
+                 pipeline: DataDrivenPipeline | None = None):
+        if cfg.keyed is None and (engine is None or pipeline is None):
+            raise ValueError("a StreamConfig without a keyed plan needs "
+                             "a rule engine and a pipeline")
         if cfg.fused and engine.table() is None:
             raise ValueError(
                 "StreamConfig(fused=True) needs a tabular RuleEngine "
@@ -469,14 +559,17 @@ class StreamExecutor:
     # -- state ------------------------------------------------------------
     def init_state(self, feature_dim: int) -> StreamState:
         cfg = self.cfg
+        keyed = cfg.keyed is not None
         return StreamState(
-            rb=rbuf.create(cfg.capacity, (META_COLS + feature_dim,)),
+            rb=rbuf.create(cfg.capacity, (META_COLS + feature_dim,),
+                           cfg.row_dtype),
             carry=jnp.zeros((cfg.carry_len, META_COLS + feature_dim),
-                            jnp.float32),
+                            cfg.row_dtype),
             carry_valid=jnp.zeros((cfg.carry_len,), bool),
             max_ts=jnp.asarray(jnp.finfo(jnp.float32).min),
-            metrics=_zero_metrics(feature_dim),
+            metrics=_zero_metrics(feature_dim, keyed),
             adm=I.admission_init(cfg.admission),
+            keyed=K.keyed_init(cfg.keyed) if keyed else None,
         )
 
     @property
@@ -550,6 +643,8 @@ class StreamExecutor:
         self._budget = int(budget)
 
     def _effective_budget(self) -> int:
+        if self.pipeline is None:              # keyed: no core stage
+            return 0
         cap = self.pipeline.core_capacity
         if self._budget is None:
             return cap if cap is not None else self.cfg.windows_per_step
@@ -564,6 +659,9 @@ class StreamExecutor:
         # the Python body runs exactly once per jit trace, so this
         # counts (re)traces without reaching into jit internals
         self._traces += 1
+        if self.cfg.keyed is not None:
+            return self._keyed_step(state, items, ts, lat_hist, lineage,
+                                    last_dt, now, mode)
         ing = ingest_and_window(self.cfg, self.engine, state, items, ts,
                                 mode=mode, now=now)
 
@@ -597,6 +695,35 @@ class StreamExecutor:
                                      ing.window_count, ing.consequence,
                                      escalated, result.outputs), \
             lat_hist, lineage
+
+    def _keyed_step(self, state: StreamState, items: jnp.ndarray,
+                    ts: jnp.ndarray, lat_hist: jnp.ndarray,
+                    lineage: jnp.ndarray, last_dt: jnp.ndarray,
+                    now: jnp.ndarray, mode: jnp.ndarray
+                    ) -> tuple[StreamState, K.KeyedOutput, jnp.ndarray,
+                               jnp.ndarray]:
+        """The step of a keyed plan: the shared front half, then the
+        keyed stage on the rows' item words."""
+        cfg = self.cfg
+        f = ingest_front(cfg, state, items, ts, mode=mode, now=now)
+        kst, out, dk = K.keyed_step(cfg.keyed, state.keyed,
+                                    f.rows[:, META_COLS:], f.valid,
+                                    int(cfg.lateness))
+        with jax.named_scope("obs:metrics"):
+            m = state.metrics
+            metrics = m._replace(
+                **_admission_counters(
+                    m, f, jnp.sum(f.valid.astype(jnp.int32)) + f.n_late),
+                drift_counts=m.drift_counts + f.drift,
+                keyed=K.KeyedMetrics(*(a + b for a, b in zip(m.keyed, dk))))
+            lat_hist = OL.histogram_update(lat_hist, last_dt)
+        with jax.named_scope("obs:lineage"):
+            stamp = jax.lax.bitcast_convert_type(f.rows[:, 1], jnp.float32)
+            lineage = OL.lineage_update(
+                lineage, {"queueing": (now - stamp, f.dequeued)})
+        new_state = state._replace(rb=f.rb, max_ts=f.max_ts,
+                                   metrics=metrics, adm=f.adm, keyed=kst)
+        return new_state, out, lat_hist, lineage
 
     # -- public API ---------------------------------------------------------
     def step(self, state: StreamState, items: jnp.ndarray,
@@ -634,6 +761,9 @@ class StreamExecutor:
         (re)trace, whose wall time is compile time: that sample is
         withheld (``warmup_excluded``) so one warmup tick can never
         masquerade as a million-microsecond p99.
+
+        With a keyed plan ``items`` and ``ts`` are int32 words (event
+        time an integer) and the output is a ``stream.keyed.KeyedOutput``.
 
         With a tracer installed a call is the span tree ``stream.step``
         > ``stream.dispatch`` > ``stream.operands`` (the scalar operands

@@ -86,9 +86,10 @@ from repro.obs.trace import NULL_TRACER
 from repro.core.pipeline import DataDrivenPipeline
 from repro.data import ringbuffer as rbuf
 from repro.stream import ingest as SI
-from repro.stream.executor import (META_COLS, StepOutput, StreamConfig,
-                                   StreamMetrics, StreamState, _zero_metrics,
-                                   advance_metrics, ingest_and_window)
+from repro.stream.executor import (COUNTERS, META_COLS, StepOutput,
+                                   StreamConfig, StreamMetrics, StreamState,
+                                   _zero_metrics, advance_metrics,
+                                   ingest_and_window)
 from repro.stream.fleet import federation as F
 from repro.stream.fleet import routing as FR
 
@@ -244,9 +245,9 @@ class FleetMetrics(NamedTuple):
 
         return {
             "shard": {k: _shard(v) for k, v in
-                      zip(StreamMetrics._fields, host.shard)},
+                      zip(COUNTERS, host.shard)},
             "fleet": {k: _fleet(v) for k, v in
-                      zip(StreamMetrics._fields, host.fleet)},
+                      zip(COUNTERS, host.fleet)},
             "escalations_sent": _shard(host.escalations_sent),
             "fog_shed": _shard(host.fog_shed),
             "core_received": _shard(host.core_received),
@@ -297,6 +298,9 @@ class FleetExecutor:
 
     def __init__(self, cfg: FleetConfig, engine: R.RuleEngine,
                  pipeline: DataDrivenPipeline, mesh: Mesh | None = None):
+        if cfg.stream.keyed is not None:
+            raise ValueError("a keyed plan runs on one StreamExecutor; "
+                             "the fleet has no keyed path")
         ci = pipeline.core_index
         if ci is None or ci != len(pipeline.stages) - 1:
             raise ValueError("fleet pipeline needs exactly one core stage, "
@@ -639,7 +643,7 @@ class FleetExecutor:
         return FleetState(
             shard=jax.tree.map(tile, shard),
             fleet=StreamMetrics(
-                *(zero() for _ in StreamMetrics._fields[:-1]),
+                *(zero() for _ in COUNTERS[:-1]),
                 drift_counts=jnp.zeros((E, feature_dim), jnp.int32)),
             escalations_sent=zero(), fog_shed=zero(), core_received=zero(),
             core_processed=zero(), fleet_core_overflow=zero(),
@@ -1002,7 +1006,7 @@ class FleetExecutor:
                              f"old indices, got {fold_counters} with "
                              f"departed={departed_idx}")
         for src, dst in fold_counters.items():
-            for arr in (list(host.shard.metrics)
+            for arr in (list(host.shard.metrics[:-1])   # less keyed
                         + [host.escalations_sent, host.fog_shed,
                            host.core_received, host.core_processed,
                            host.late_excluded]):

@@ -462,9 +462,11 @@ def allreduce_metrics(metrics, axis_name):
     """All-reduce a NamedTuple of counters over the fleet axis.  Scalar
     leaves ride ONE stacked psum (not one collective per counter);
     array-valued leaves (the [D] per-field ``drift_counts``) can't join
-    the stack — shapes differ — so each gets its own psum."""
+    the stack — shapes differ — so each gets its own psum.  A field
+    left None (``StreamMetrics.keyed`` without a keyed plan) stays so."""
     leaves = list(metrics)
-    scalar = [i for i, v in enumerate(leaves) if jnp.ndim(v) == 0]
+    scalar = [i for i, v in enumerate(leaves)
+              if v is not None and jnp.ndim(v) == 0]
     out = list(leaves)
     if scalar:
         tot = jax.lax.psum(jnp.stack([leaves[i] for i in scalar]),
@@ -472,6 +474,6 @@ def allreduce_metrics(metrics, axis_name):
         for j, i in enumerate(scalar):
             out[i] = tot[j]
     for i in range(len(leaves)):
-        if i not in scalar:
+        if i not in scalar and leaves[i] is not None:
             out[i] = jax.lax.psum(leaves[i], axis_name)
     return type(metrics)(*out)

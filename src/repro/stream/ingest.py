@@ -150,12 +150,15 @@ def admission_init(plan: AdmissionPlan) -> AdmissionState:
 
 def admission_gate(plan: AdmissionPlan, adm: AdmissionState,
                    ts: jnp.ndarray, items: jnp.ndarray,
-                   offer_mask: jnp.ndarray | None) -> AdmissionGate:
+                   offer_mask: jnp.ndarray | None,
+                   row_dtype: str = "float32") -> AdmissionGate:
     """stamp -> dedupe -> contract, as fixed-shape masked ops.
 
     The event identity is ``hash(event_ts ++ features)`` — the
     producer's wire content, NOT the local ingest stamp, so a
-    redelivery stamped at a later wall time still dedupes.  Dedupe
+    redelivery stamped at a later wall time still dedupes.  The wire
+    words are ``row_dtype``'s, as the ring holds them: float32 bits, or
+    exact int32 words.  Dedupe
     runs first; contract-rejected rows are never recorded in the
     window, so a re-send of a rejected row is judged *fresh* again and
     rejected again by the contract — by design, every delivery of a
@@ -166,8 +169,8 @@ def admission_gate(plan: AdmissionPlan, adm: AdmissionState,
     offered = jnp.ones((n,), bool) if offer_mask is None \
         else jnp.asarray(offer_mask, bool)
     wire = jnp.concatenate(
-        [jnp.asarray(ts, jnp.float32)[:, None],
-         jnp.asarray(items, jnp.float32)], axis=1)
+        [jnp.asarray(ts, row_dtype)[:, None],
+         jnp.asarray(items, row_dtype)], axis=1)
     hashes = row_hash(wire)
     fresh, dup = dedupe_window(hashes, offered, adm.seen)
     if plan.contract is None:

@@ -42,12 +42,13 @@ def test_stream_metrics_golden_keys():
         "items_deduped", "items_backfilled",
         "windows_emitted", "rules_fired", "windows_escalated",
         "windows_stored", "windows_dropped", "core_overflow",
-        "drift_counts")
+        "drift_counts", "keyed")
     m = StreamMetrics(*(jnp.zeros((), jnp.int32)
-                        for _ in StreamMetrics._fields[:-1]),
+                        for _ in StreamMetrics._fields[:-2]),
                       drift_counts=jnp.zeros((3,), jnp.int32))
     d = m.as_dict()
-    assert tuple(d) == StreamMetrics._fields
+    # a keyed plan's counters appear only with one (stream.keyed)
+    assert tuple(d) == StreamMetrics._fields[:-1]
     assert all(v == 0 for k, v in d.items() if k != "drift_counts")
     assert d["drift_counts"] == [0, 0, 0]      # per-field -> list
 
@@ -59,7 +60,7 @@ def test_fleet_metrics_golden_keys():
         "core_received", "core_processed", "fleet_core_overflow",
         "late_excluded", "watermark", "region_watermark")
     zeros = StreamMetrics(*(jnp.zeros((2,), jnp.int32)
-                            for _ in StreamMetrics._fields[:-1]),
+                            for _ in StreamMetrics._fields[:-2]),
                           drift_counts=jnp.zeros((2, 3), jnp.int32))
     m = FleetMetrics(shard=zeros, fleet=zeros,
                      escalations_sent=jnp.zeros((2,), jnp.int32),
@@ -72,8 +73,8 @@ def test_fleet_metrics_golden_keys():
                      region_watermark=jnp.zeros((2,), jnp.float32))
     d = m.as_dict()
     assert tuple(d) == FleetMetrics._fields
-    assert tuple(d["shard"]) == StreamMetrics._fields
-    assert tuple(d["fleet"]) == StreamMetrics._fields
+    assert tuple(d["shard"]) == StreamMetrics._fields[:-1]
+    assert tuple(d["fleet"]) == StreamMetrics._fields[:-1]
     assert d["shard"]["steps"] == [0, 0]       # per-shard -> list
     assert d["fleet"]["steps"] == 0            # replicated -> scalar
     assert d["shard"]["drift_counts"] == [[0, 0, 0], [0, 0, 0]]
@@ -409,7 +410,7 @@ def test_stream_executor_obs(rng):
     snap = metrics_snapshot(ex, state)
     assert tuple(snap) == OX.SNAPSHOT_KEYS
     assert snap["kind"] == "StreamExecutor"
-    assert tuple(snap["metrics"]) == StreamMetrics._fields
+    assert tuple(snap["metrics"]) == StreamMetrics._fields[:-1]
     assert snap["metrics"]["steps"] == steps
     assert snap["trace_count"] == 1
     assert "stream.dispatch" in snap["stages"]

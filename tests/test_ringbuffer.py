@@ -89,3 +89,20 @@ def test_dequeue_more_than_available():
                                   [True, True, False, False, False])
     np.testing.assert_array_equal(np.asarray(out[:2, 0]), [1, 2])
     assert int(size(rb)) == 0
+
+
+def test_int32_rows_carry_exact_ids_past_2_24():
+    """An int32 ring moves integer words exactly: ids that float32 would
+    round (2**24 + 1) come back as they went in, across wraparound."""
+    rb = create(4, (2,), jnp.int32)
+    ids = np.array([[2 ** 24 + 1, -7], [2 ** 31 - 1, 2 ** 24 + 3],
+                    [5, -2 ** 31], [2 ** 30 + 1, 0]], np.int32)
+    got = []
+    for lo in (0, 2, 1, 3):
+        rb, n = enqueue(rb, jnp.asarray(ids[lo:lo + 2]))
+        rb, out, valid = dequeue(rb, 2)
+        assert int(n) == valid.sum() == len(ids[lo:lo + 2])
+        assert out.dtype == jnp.int32
+        got.append(np.asarray(out)[np.asarray(valid)])
+    np.testing.assert_array_equal(np.concatenate(got), np.concatenate(
+        [ids[0:2], ids[2:4], ids[1:3], ids[3:4]]))

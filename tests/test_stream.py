@@ -627,7 +627,7 @@ def test_metrics_as_dict_snapshot(rng):
     ex, state = _make_executor()
     state, _, _ = _feed(ex, state, rng, 3)
     d = state.metrics.as_dict()
-    assert set(d) == set(ex.init_state(3).metrics._fields)
+    assert set(d) == set(ex.init_state(3).metrics._fields[:-1])
     assert all(isinstance(v, int) for k, v in d.items()
                if k != "drift_counts")
     assert d["drift_counts"] == [0, 0, 0]    # [D] per-field -> list
@@ -673,3 +673,92 @@ def test_stream_config_validation():
     with pytest.raises(ValueError):     # int8 rides the overlap stager
         StreamConfig(micro_batch=32, window=16, stride=8,
                      ingest_int8=True)
+    with pytest.raises(ValueError):     # a keyed plan has no fused kernel
+        from repro.stream.keyed import KeyedPlan
+        StreamConfig(micro_batch=32, window=1, stride=1, fused=True,
+                     keyed=KeyedPlan(key_col=0, time_col=1, kind_col=2,
+                                     kind=0, pane_ms=10, panes_per_window=2,
+                                     capacity=128, results=1))
+
+
+# ---- keyed windows ----------------------------------------------------------
+
+def _keyed_executor(dedupe=16):
+    from repro.stream.ingest import AdmissionPlan
+    from repro.stream.keyed import KeyedPlan
+    plan = KeyedPlan(key_col=1, time_col=2, kind_col=0, kind=2, pane_ms=8,
+                     panes_per_window=2, capacity=256, results=4)
+    cfg = StreamConfig(micro_batch=32, window=1, stride=1, capacity=32,
+                       keyed=plan,
+                       admission=AdmissionPlan(dedupe_window=dedupe))
+    ex = StreamExecutor(cfg)
+    return ex, ex.init_state(3)
+
+
+def _keyed_feed(ex, state, rng, steps):
+    outs = []
+    for t in range(steps):
+        kind = rng.integers(0, 3, 32)
+        key = rng.integers(0, 20, 32)
+        time = (t * 32 + np.arange(32)) // 16        # two ticks a ms
+        items = np.stack([kind, key, time], axis=1).astype(np.int32)
+        items[:3] = items[0]                         # two re-deliveries
+        state, out = ex.step(state, jnp.asarray(items),
+                             jnp.asarray(time, jnp.int32))
+        outs.append(jax.device_get(out))
+    return state, outs
+
+
+def test_keyed_executor_single_trace_across_firing_ticks(rng):
+    ex, state = _keyed_executor()
+    state, outs = _keyed_feed(ex, state, rng, 40)
+    fired = [bool(o.fired) for o in outs]
+    assert any(fired) and not all(fired)
+    assert ex.trace_count == 1
+    assert ex._jstep._cache_size() == 1
+    m = state.metrics.as_dict()
+    assert m["windows_fired"] == m["panes_evicted"] == sum(fired)
+    assert m["key_overflow"] == 0
+
+
+def test_keyed_executor_conservation(rng):
+    ex, state = _keyed_executor()
+    state, _ = _keyed_feed(ex, state, rng, 12)
+    m = state.metrics.as_dict()
+    assert m["items_offered"] == 12 * 32
+    assert m["items_offered"] == (m["items_accepted"] + m["items_rejected"]
+                                  + m["items_deduped"])
+    assert m["items_deduped"] >= 2 * 12
+    assert m["bids_counted"] + m["events_filtered"] \
+        == m["items_dequeued"] - m["items_late"] == m["items_accepted"]
+    # the windows, rules and pipeline are not on a keyed step's path
+    assert m["windows_emitted"] == m["rules_fired"] == 0
+
+
+def test_sensor_step_lowers_to_the_same_op_count():
+    """A StreamConfig without a keyed plan takes none of its ops: the
+    step lowers to the op counts it had before keyed windows existed
+    (the configurations of ``_make_executor``, with the admission lane
+    and contract, and fused with a dedupe window)."""
+    import dataclasses
+    import re
+
+    from repro.stream.ingest import AdmissionPlan, DataContract
+
+    ex, _ = _make_executor()
+    cases = {
+        827: ex.cfg,
+        1017: dataclasses.replace(ex.cfg, admission=AdmissionPlan(
+            dedupe_window=16, contract=DataContract(lo=(-8.,) * 3,
+                                                    hi=(8.,) * 3))),
+        916: dataclasses.replace(ex.cfg, fused=True,
+                                 admission=AdmissionPlan(dedupe_window=16)),
+    }
+    for ops, cfg in cases.items():
+        e = StreamExecutor(cfg, ex.engine, ex.pipeline)
+        text = e.lower(e.init_state(3),
+                       jax.ShapeDtypeStruct((32, 3), jnp.float32),
+                       jax.ShapeDtypeStruct((32,), jnp.float32)).as_text()
+        assert "keyed" not in text
+        assert sum(1 for line in text.splitlines()
+                   if re.search(r"\b(stablehlo|chlo)\.[a-z_]+", line)) == ops

@@ -126,6 +126,28 @@ def test_stream_step_compiles(one_chip):
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2 << 30
 
 
+def test_keyed_step_compiles(one_chip):
+    """The NEXmark Q5 step at its deployment size: 65,536 rows of 25
+    int32 words, a 2**24-slot (key, pane, count) table donated with the
+    state, the firing's whole-table pass inside its conditional."""
+    from repro.stream.keyed import KeyedPlan
+
+    plan = KeyedPlan(key_col=1, time_col=6, kind_col=0, kind=2,
+                     pane_ms=5000, panes_per_window=2, capacity=1 << 24,
+                     results=64)
+    ex = StreamExecutor(StreamConfig(
+        micro_batch=ROWS, window=1, stride=1, capacity=ROWS,
+        keyed=plan,
+        admission=AdmissionPlan(dedupe_window=1024)))
+    state = _shapes(jax.eval_shape(lambda: ex.init_state(25)), one_chip)
+    x = jax.ShapeDtypeStruct((ROWS, 25), jnp.int32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=one_chip)
+    mem = ex.lower(state, x, t).compile().memory_analysis()
+    table = 3 * 4 << 24
+    assert mem.alias_size_in_bytes >= table         # updated in place
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 2 << 30
+
+
 def test_fleet_step_compiles(mesh):
     engine = _engine()
     scfg = _stream_config(ROWS)
